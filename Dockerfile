@@ -3,8 +3,11 @@
 # different commands — reference analogue: /root/reference/Dockerfile,
 # one binary image).
 #
-# The TPU runtime (libtpu) is provided by the node/runtime class on TPU
-# VMs; on CPU-only nodes the engine falls back to jax CPU automatically.
+# The TPU runtime (libtpu) is installed with jax[tpu] below.  On a node
+# without a TPU, jax runs on the CPU; a failed device probe is logged at
+# error level and counted (device_probe_failures), and the engine serves
+# from the scalar oracle.  `python chip_smoke.py` fails unless it finds a
+# TPU.
 
 FROM python:3.12-slim
 
@@ -14,9 +17,11 @@ FROM python:3.12-slim
 RUN apt-get update && apt-get install -y --no-install-recommends \
         gcc libc6-dev openssl && rm -rf /var/lib/apt/lists/*
 
-RUN pip install --no-cache-dir "jax[tpu]" -f \
-        https://storage.googleapis.com/jax-releases/libtpu_releases.html \
-    || pip install --no-cache-dir jax jaxlib
+# the versions chip_smoke.py passed with on a v5e chip (PR 21); a failed
+# install fails the build
+RUN pip install --no-cache-dir "jax[tpu]==0.9.0" "jaxlib==0.9.0" \
+        "libtpu==0.0.34" -f \
+        https://storage.googleapis.com/jax-releases/libtpu_releases.html
 RUN pip install --no-cache-dir numpy pyyaml
 
 WORKDIR /app

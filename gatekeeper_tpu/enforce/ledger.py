@@ -38,16 +38,15 @@ at emit time — the ring is a debugging window, not the stream)."""
 
 
 def pages_mode() -> bool:
-    """GATEKEEPER_PAGES: the paged O(dirty) sweep + VerdictLedger.
-    Default ON as of the reactor PR — the path has soaked under the
-    chaos harness with watch-class faults injected (gap, duplicate,
-    reorder, stall, flood) with the ledger stream bit-identical to the
-    full-sweep diff throughout (ROADMAP item 2 graduation).  ``off``
-    selects the legacy full-kind path (with PR-10 footprint selective
-    invalidation) — still maintained as the shipping oracle every
-    parity gate diffs against."""
+    """GATEKEEPER_PAGES: the paged O(dirty) sweep + VerdictLedger
+    (and the watch reactor cmd/manager attaches to it).  Default OFF:
+    the ledger is uncapped, so its cold entry confirms every live row
+    on the scalar oracle — hours at 1M rows, none of it on the device
+    (PERF.md, Findings PR 21).  ``off`` runs the per-kind device sweep
+    (with PR-10 footprint selective invalidation), the oracle every
+    parity gate diffs against.  ``on`` opts a deployment in."""
     import os
-    return os.environ.get("GATEKEEPER_PAGES", "on").lower() in (
+    return os.environ.get("GATEKEEPER_PAGES", "off").lower() in (
         "on", "1", "true")
 
 

@@ -5,8 +5,8 @@ dirty bits were still folded in only when the next audit sweep ran, so
 sweep cadence bounded detection latency.  The reactor couples the live
 watch stream to the row-paged store: a single-object event becomes a
 single-page re-eval (``Client.react`` -> ``JaxDriver.react_kind``) with
-no sweep in between — wrapped in the robustness machinery that earns
-``GATEKEEPER_PAGES`` its default-on:
+no sweep in between (``GATEKEEPER_PAGES=on``; off by default) — wrapped
+in this robustness machinery:
 
 * a **bounded per-kind event queue** with page-granular coalescing
   (repeat events for an object collapse to the latest; events landing

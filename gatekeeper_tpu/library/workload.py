@@ -143,3 +143,50 @@ def make_mixed(rng, n):
                             ["alice", "system:anonymous", "system:unauthenticated"])}]})
     return out
 
+
+
+def make_pods(n, rng):
+    """The north-star inventory: ``n`` Pods over 50 namespaces, each
+    with 0-10 of ten labels and 1-3 containers drawn from four
+    registries with cpu/memory limits."""
+    label_pool = [f"l{j}" for j in range(10)]
+    repos = ["gcr.io/org/", "docker.io/", "quay.io/team/", "ghcr.io/x/"]
+    out = []
+    for i in range(n):
+        labels = {k: "v" for k in label_pool if rng.random() < 0.35}
+        containers = [{
+            "name": f"c{j}",
+            "image": rng.choice(repos) + f"app{rng.randrange(50)}:{rng.randrange(9)}",
+            "resources": {"limits": {
+                "cpu": rng.choice(["100m", "250m", "1", "2", "4000m"]),
+                "memory": rng.choice(["256Mi", "1Gi", "4Gi"])}},
+        } for j in range(rng.randint(1, 3))]
+        out.append({"apiVersion": "v1", "kind": "Pod",
+                    "metadata": {"name": f"pod{i:07d}",
+                                 "namespace": f"ns{i % 50}", "labels": labels},
+                    "spec": {"containers": containers}})
+    return out
+
+
+def north_star_docs(rng, per_kind):
+    """(template docs, constraint docs) of the north-star policy set:
+    K8sRequiredLabels, K8sAllowedRepos and K8sContainerLimits with
+    ``per_kind`` constraints each (201 at the BASELINE.json size)."""
+    from gatekeeper_tpu.library.templates import (LIBRARY, constraint_doc,
+                                                  template_doc)
+    kinds = ("K8sRequiredLabels", "K8sAllowedRepos", "K8sContainerLimits")
+    templates = [template_doc(k, LIBRARY[k][0]) for k in kinds]
+    constraints = []
+    for j in range(per_kind):
+        constraints.append(constraint_doc(
+            "K8sRequiredLabels", f"labels-{j:03d}",
+            {"labels": rng.sample([f"l{x}" for x in range(10)], k=2)}))
+        constraints.append(constraint_doc(
+            "K8sAllowedRepos", f"repos-{j:03d}",
+            {"repos": rng.sample(["gcr.io/", "docker.io/", "quay.io/",
+                                  "ghcr.io/"], k=2)}))
+        constraints.append(constraint_doc(
+            "K8sContainerLimits", f"cpu-{j:03d}",
+            {"cpu": rng.choice(["500m", "1", "2"]),
+             "memory": rng.choice(["512Mi", "2Gi"])}))
+    return templates, constraints

@@ -33,12 +33,8 @@ import dataclasses
 
 import numpy as np
 
-try:                                    # CPython 3.11+
-    import re._parser as _sre_parse
-    import re._constants as _sre
-except ImportError:                     # pragma: no cover - older layouts
-    import sre_parse as _sre_parse      # type: ignore
-    import sre_constants as _sre        # type: ignore
+import re._constants as _sre
+import re._parser as _sre_parse
 
 TERM = 0                    # per-string terminator byte (strings are NUL-free)
 
@@ -49,7 +45,7 @@ TERM = 0                    # per-string terminator byte (strings are NUL-free)
 TABLE_MIN_UNIQUES = 4096
 TABLE_DEVICE_MIN_UNIQUES = 262144
 
-DFA_VERSION = 1
+DFA_VERSION = 2
 """Automaton format/semantics version.  Bump whenever compile_dfa's
 output for a given pattern can change — snapshot entries are keyed by
 (pattern, DFA_VERSION), so a stale persisted table can never serve a
@@ -166,7 +162,19 @@ def _category_bytes(cat) -> frozenset:
     return (_ALL - base) if neg else base
 
 
-def _in_bytes(items) -> frozenset:
+_UPPER = frozenset(range(ord("A"), ord("Z") + 1))
+_LOWER = frozenset(range(ord("a"), ord("z") + 1))
+
+
+def _fold(syms) -> frozenset:
+    """ASCII case closure of a byte set, for ``(?i)``.  Inputs are
+    ASCII (the packer routes the rest to the host path), and among
+    ASCII characters Python's IGNORECASE pairs only the letters."""
+    syms = frozenset(syms)
+    return syms | {b ^ 0x20 for b in syms if b in _UPPER or b in _LOWER}
+
+
+def _in_bytes(items, icase: bool = False) -> frozenset:
     out: set[int] = set()
     negate = False
     for op, arg in items:
@@ -185,6 +193,8 @@ def _in_bytes(items) -> frozenset:
             out.update(_category_bytes(arg))
         else:
             raise _Unsupported(f"class item {op}")
+    if icase:
+        out = _fold(out)
     return frozenset(_ALL - out) if negate else frozenset(out)
 
 
@@ -194,8 +204,9 @@ def _literal_bytes(cp: int) -> list[frozenset]:
 
 
 def _build(nfa: _NFA, tokens, start: int, end: int,
-           at_start: bool) -> None:
-    """Wire `tokens` between NFA states start..end."""
+           at_start: bool, icase: bool = False) -> None:
+    """Wire `tokens` between NFA states start..end (`icase`: the
+    pattern's global ``(?i)``)."""
     if not tokens:
         # empty sequence matches the empty string ("", "^", "a|") —
         # without this epsilon the DFA would reject everything
@@ -207,7 +218,11 @@ def _build(nfa: _NFA, tokens, start: int, end: int,
         last = i == n - 1
         nxt = end if last else nfa.state()
         if op is _sre.LITERAL:
-            seq = _literal_bytes(arg)
+            if icase and arg > 127:
+                # (?i)ſ matches ASCII s: non-ASCII folds stay on the host
+                raise _Unsupported("non-ASCII literal under (?i)")
+            seq = [_fold(syms) for syms in _literal_bytes(arg)] \
+                if icase else _literal_bytes(arg)
             mid = cur
             for j, syms in enumerate(seq):
                 dst = nxt if j == len(seq) - 1 else nfa.state()
@@ -216,9 +231,10 @@ def _build(nfa: _NFA, tokens, start: int, end: int,
         elif op is _sre.NOT_LITERAL:
             if arg > 127:
                 raise _Unsupported("non-ASCII not-literal")
-            nfa.add_edge(cur, _ALL - {arg}, nxt)
+            nfa.add_edge(cur, _ALL - (_fold({arg}) if icase else {arg}),
+                         nxt)
         elif op is _sre.IN:
-            nfa.add_edge(cur, _in_bytes(arg), nxt)
+            nfa.add_edge(cur, _in_bytes(arg, icase), nxt)
         elif op is _sre.ANY:
             nfa.add_edge(cur, _ANY, nxt)
         elif op is _sre.AT:
@@ -235,14 +251,14 @@ def _build(nfa: _NFA, tokens, start: int, end: int,
             _g, add_flags, del_flags, sub = arg
             if add_flags or del_flags:
                 raise _Unsupported("inline flags")
-            _build(nfa, list(sub), cur, nxt, at_start and i == 0)
+            _build(nfa, list(sub), cur, nxt, at_start and i == 0, icase)
         elif op is _sre.BRANCH:
             _none, alts = arg
             for alt in alts:
                 a, b = nfa.state(), nfa.state()
                 nfa.add_eps(cur, a)
                 nfa.add_eps(b, nxt)
-                _build(nfa, list(alt), a, b, at_start and i == 0)
+                _build(nfa, list(alt), a, b, at_start and i == 0, icase)
         elif op in (_sre.MAX_REPEAT, _sre.MIN_REPEAT):
             lo, hi, sub = arg
             sub = list(sub)
@@ -252,19 +268,19 @@ def _build(nfa: _NFA, tokens, start: int, end: int,
             mid = cur
             for _ in range(lo):                      # mandatory copies
                 dst = nfa.state()
-                _build(nfa, sub, mid, dst, False)
+                _build(nfa, sub, mid, dst, False, icase)
                 mid = dst
             if hi is _sre.MAXREPEAT:                 # star tail
                 a = nfa.state()
                 nfa.add_eps(mid, a)
                 b = nfa.state()
-                _build(nfa, sub, a, b, False)
+                _build(nfa, sub, a, b, False, icase)
                 nfa.add_eps(b, a)
                 nfa.add_eps(a, nxt)
             else:
                 for _ in range(hi - lo):             # optional copies
                     dst = nfa.state()
-                    _build(nfa, sub, mid, dst, False)
+                    _build(nfa, sub, mid, dst, False, icase)
                     nfa.add_eps(mid, nxt)
                     mid = dst
                 nfa.add_eps(mid, nxt)
@@ -281,6 +297,12 @@ def _compile(pattern: str) -> DFA:
         parsed = _sre_parse.parse(pattern)
     except Exception as e:                  # noqa: BLE001 - sre raises re.error
         raise _Unsupported(f"unparseable: {e}") from None
+    # global inline flags ("(?i)..." at the start) live on the parse
+    # state, not in the token list
+    flags = parsed.state.flags
+    if flags & (_sre.SRE_FLAG_MULTILINE | _sre.SRE_FLAG_DOTALL):
+        raise _Unsupported("inline flags")
+    icase = bool(flags & _sre.SRE_FLAG_IGNORECASE)
     tokens = list(parsed)
     anchored_left = bool(tokens) and tokens[0][0] is _sre.AT \
         and tokens[0][1] in (_sre.AT_BEGINNING, _sre.AT_BEGINNING_STRING)
@@ -288,7 +310,7 @@ def _compile(pattern: str) -> DFA:
         tokens = tokens[1:]
     nfa = _NFA()
     start, end = nfa.state(), nfa.state()
-    _build(nfa, tokens, start, end, at_start=True)
+    _build(nfa, tokens, start, end, at_start=True, icase=icase)
 
     def closure(states: frozenset) -> frozenset:
         stack, seen = list(states), set(states)

@@ -260,7 +260,7 @@ class BackendSupervisor:
             try:
                 if (faults.active("probe_hang")
                         or os.environ.get("GATEKEEPER_PROBE_TEST_HANG") == "1"):
-                    time.sleep(3600)    # simulated dead tunnel
+                    time.sleep(3600)    # simulated unanswering backend
                 import jax
                 import jax.numpy as jnp
                 devs = jax.devices()
@@ -361,7 +361,7 @@ class BackendSupervisor:
     def _pin_children_to_cpu(self) -> None:
         """Keep ``device_probe.child_env`` coherent with supervisor
         state: while degraded, children must not walk into the same
-        dead plugin (and the probe verdict they'd inherit agrees)."""
+        failed backend (and the probe verdict they'd inherit agrees)."""
         from gatekeeper_tpu.utils import device_probe
         with self._lock:
             poisoned = self._state == POISONED

@@ -27,13 +27,17 @@ _lock = threading.Lock()
 
 
 def cache_root() -> str:
-    """The on-disk root shared by every persistence tier: the XLA
-    executable cache lives under ``<root>/<backend-subdir>``, and the
-    warm-restart snapshots (resilience/snapshot.py) default to
-    ``<root>/snapshots`` when GATEKEEPER_SNAPSHOT_DIR is unset by the
-    embedding application."""
-    return os.environ.get("GATEKEEPER_XLA_CACHE_DIR") \
-        or os.path.join(os.getcwd(), ".gatekeeper_xla_cache")
+    """The on-disk root shared by every persistence tier: a fixed
+    directory inside the checkout, derived from this package's location
+    (never from the working directory, a temp name or a pid), so every
+    process run from any directory finds the same entries.  The XLA
+    executable cache lives under ``<root>/<backend-subdir>`` unless
+    JAX_COMPILATION_CACHE_DIR places it elsewhere, and the warm-restart
+    snapshots (resilience/snapshot.py) default to ``<root>/snapshots``
+    when GATEKEEPER_SNAPSHOT_DIR is unset by the embedding
+    application."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(pkg), ".gatekeeper_xla_cache")
 
 
 def host_fingerprint() -> str:
@@ -44,8 +48,7 @@ def host_fingerprint() -> str:
     compiled on a host with e.g. AMX/AVX-512 loaded on a host without
     those features can SIGILL and abort the whole process mid-sweep —
     observed when a working tree (with its untracked cache dir) moves
-    between the bench host, the remote compile service, and other
-    machines.  Keying the cache directory by the CPU's feature flags
+    between machines.  Keying the cache directory by the CPU's feature flags
     means a foreign host's artifacts land in a directory this host
     never reads.
     """
@@ -116,21 +119,22 @@ def resolve_cache_path(backend: str, root: str) -> str | None:
 
 
 def enable_persistent_cache(path: str | None = None) -> str:
-    """Idempotently point JAX's persistent compilation cache at a
-    machine-safe subdirectory of `path` (default:
-    $GATEKEEPER_XLA_CACHE_DIR or ./.gatekeeper_xla_cache).  A cache dir
-    the embedding application already configured wins — it is never
-    clobbered.  Returns the path actually in effect ("" = persistence
-    disabled for this backend)."""
+    """Idempotently turn on JAX's persistent compilation cache.
+
+    Where JAX_COMPILATION_CACHE_DIR is set (JAX reads it into
+    ``jax_compilation_cache_dir`` at import), or the embedding
+    application configured a directory, the cache lives exactly there:
+    no other directory and no subdirectory is set.  Otherwise it goes to
+    a machine-safe subdirectory of `path` (default: ``cache_root()``).
+    Returns the path in effect ("" = persistence disabled for this
+    backend)."""
     global _enabled
     with _lock:
         import jax
         existing = getattr(jax.config, "jax_compilation_cache_dir", None)
-        if existing:
-            _enabled = True
-            return existing
         if _enabled:
-            return getattr(jax.config, "jax_compilation_cache_dir", "") or ""
+            return existing or ""
+        _enabled = True
         # backend identity comes from the bounded probe, never from a
         # direct jax.default_backend() call: in a process whose probe
         # timed out (utils/device_probe), the hung probe thread may hold
@@ -138,19 +142,31 @@ def enable_persistent_cache(path: str | None = None) -> str:
         # block cache setup (and with it driver construction) forever.
         from gatekeeper_tpu.utils.device_probe import probe_devices
         res = probe_devices()
-        if res.poisoned:
-            _enabled = True
-            return ""       # no usable backend: persistence is moot
         backend = res.platform if res.ok else "unknown"
-        root = path or cache_root()
-        path = resolve_cache_path(backend, root)
-        _enabled = True
+        if existing:
+            # placed from outside: JAX caches there with its own
+            # thresholds; widen them only where this module would cache
+            # at all (not CPU, unless GATEKEEPER_XLA_CACHE_CPU=1)
+            if not res.poisoned and \
+                    resolve_cache_path(backend, existing) is not None:
+                _set_thresholds(jax)
+            return existing
+        if res.poisoned:
+            return ""       # no usable backend: persistence is moot
+        path = resolve_cache_path(backend, path or cache_root())
         if path is None:
             return ""
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+        _set_thresholds(jax)
         return path
+
+
+def _set_thresholds(jax) -> None:
+    """Persist every executable: the per-kind programs compile in well
+    under JAX's default 1 s threshold, and those are the ones a restart
+    must not recompile."""
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
 
 
 _upgraded_keys: set | None = None

@@ -21,13 +21,18 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 from gatekeeper_tpu.utils.compile_cache import (
     PersistentCacheStats,
     _backend_subdir,
+    cache_root,
     host_fingerprint,
     persistent_cache_stats,
     resolve_cache_path,
 )
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class TestHostFingerprint:
@@ -70,6 +75,75 @@ class TestResolvePolicy:
         assert os.path.basename(p) != "tpu"
 
 
+class TestCacheLocation:
+    def test_root_is_fixed_inside_the_checkout(self, monkeypatch, tmp_path):
+        root = cache_root()
+        assert root == os.path.join(REPO, ".gatekeeper_xla_cache")
+        monkeypatch.chdir(tmp_path)
+        assert cache_root() == root
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cache_root() == root      # snapshots stay in the checkout
+
+    def test_env_dir_holds_every_entry(self, tmp_path):
+        """JAX_COMPILATION_CACHE_DIR: compiled entries land in exactly
+        that directory (no subdirectory), and nothing lands in the
+        working directory or the checkout's default root."""
+        jc, cwd = tmp_path / "jc", tmp_path / "cwd"
+        cwd.mkdir()
+        code = (
+            "import jax, os, sys\n"
+            "sys.path.insert(0, %r)\n"
+            "from gatekeeper_tpu.utils.compile_cache import "
+            "enable_persistent_cache\n"
+            "assert enable_persistent_cache() == os.environ["
+            "'JAX_COMPILATION_CACHE_DIR'], jax.config.jax_compilation_cache_dir\n"
+            "jax.config.update('jax_persistent_cache_min_compile_time_secs',"
+            " 0)\n"
+            "import jax.numpy as jnp\n"
+            "jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()\n"
+            "print('OK')\n") % REPO
+        before = os.path.exists(cache_root())
+        env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(jc),
+                   JAX_PLATFORMS="cpu")
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             cwd=cwd, capture_output=True, text=True,
+                             timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        entries = list(jc.iterdir())
+        assert entries and all(p.is_file() for p in entries), entries
+        assert list(cwd.iterdir()) == []
+        assert os.path.exists(cache_root()) == before
+
+    @pytest.mark.parametrize("opt_in,widened", [(None, False), ("1", True)])
+    def test_env_dir_keeps_jax_thresholds_on_cpu(self, tmp_path, opt_in,
+                                                 widened):
+        """A directory placed from outside keeps JAX's own thresholds on
+        CPU (its executables are not safe to reload from several
+        dispatch threads) unless GATEKEEPER_XLA_CACHE_CPU=1 opts in."""
+        code = (
+            "import jax, sys\n"
+            "sys.path.insert(0, %r)\n"
+            "defaults = (jax.config.jax_persistent_cache_min_compile_time_secs,"
+            " jax.config.jax_persistent_cache_min_entry_size_bytes)\n"
+            "from gatekeeper_tpu.utils.compile_cache import "
+            "enable_persistent_cache\n"
+            "assert enable_persistent_cache()\n"
+            "now = (jax.config.jax_persistent_cache_min_compile_time_secs,"
+            " jax.config.jax_persistent_cache_min_entry_size_bytes)\n"
+            "print('WIDENED' if now != defaults else 'DEFAULTS', defaults,"
+            " now)\n") % REPO
+        env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+                   JAX_PLATFORMS="cpu")
+        env.pop("GATEKEEPER_XLA_CACHE_CPU", None)
+        if opt_in:
+            env["GATEKEEPER_XLA_CACHE_CPU"] = opt_in
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.startswith("WIDENED" if widened else "DEFAULTS"), \
+            out.stdout
+
+
 class TestPersistentCacheStats:
     def test_counts_monitoring_events(self):
         assert persistent_cache_stats() is persistent_cache_stats()
@@ -91,7 +165,7 @@ class TestPersistentCacheStats:
         assert s.snapshot() == {"hits": 0, "misses": 0, "requests": 0,
                                 "wired": True}
 
-    def test_wired_to_real_event_stream(self):
+    def test_wired_to_real_event_stream(self, tmp_path):
         # fresh process (this one latched its cache state long ago):
         # enable the cache before any compile, jit something, and the
         # stats must see the cache-eligible request
@@ -111,8 +185,7 @@ class TestPersistentCacheStats:
             "print('OK', d)\n"
         ) % os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         env = dict(os.environ,
-                   GATEKEEPER_XLA_CACHE_CPU="1",
-                   GATEKEEPER_XLA_CACHE_DIR="/tmp/gk_cache_stats_test",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path),
                    JAX_PLATFORMS="cpu")
         out = subprocess.run([sys.executable, "-c", code], env=env,
                              capture_output=True, text=True, timeout=180)

@@ -135,7 +135,7 @@ def test_full_sweep_device_path_parity_and_phases(monkeypatch):
     # plan-driven-sharding, and continuous-enforcement stanzas
     c.audit()
     assert jd.last_sweep_phases["full"] is False
-    assert set(jd.last_sweep_phases) <= {"full", "footprint", "shard",
+    assert set(jd.last_sweep_phases) <= {"full", "footprint", "shard", "dispatch",
                                          "pages", "devpages"}
 
     # oracle parity for the same workload

@@ -311,7 +311,7 @@ class TestAttribution:
         # Stage-5/-6 selective-invalidation and sharding stanzas)
         _audit(jd, full=False)
         assert jd.last_sweep_phases["full"] is False
-        assert set(jd.last_sweep_phases) <= {"full", "footprint", "shard",
+        assert set(jd.last_sweep_phases) <= {"full", "footprint", "shard", "dispatch",
                                              "pages", "devpages"}
 
 
@@ -441,11 +441,14 @@ class TestEndpointsUnderLoad:
                 with urllib.request.urlopen(req, timeout=10) as resp:
                     json.loads(resp.read())
 
+            admitted = threading.Event()
+
             def hammer(tid):
                 i = 0
                 try:
                     while not stop.is_set():
                         admit(tid * 1000 + i)
+                        admitted.set()
                         i += 1
                 except Exception as e:      # noqa: BLE001
                     errors.append(e)
@@ -454,6 +457,8 @@ class TestEndpointsUnderLoad:
                        for t in range(3)]
             for t in threads:
                 t.start()
+            # the histogram exists once the first admission completed
+            assert admitted.wait(30), errors
             deadline = time.monotonic() + 2.0
             reads = 0
             while time.monotonic() < deadline:

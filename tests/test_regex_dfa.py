@@ -30,6 +30,12 @@ GENERATED_PATTERNS = [
     "x.y", "\\d+\\.\\d+", "\\w+@\\w+", "\\s", "v[0-9]+(-rc[0-9]+)?$",
 ]
 
+# a global (?i) lives on the parse state, not in the token list
+ICASE_PATTERNS = [
+    "(?i)(secret|token)", "(?i)^[a-z]+$", "(?i)[^k]x", "(?i)Te?ST",
+    "(?i)[A-C0-9]{2}", "(?i)LATEST$",
+]
+
 
 def _corpus(rng, n=400):
     out = ["", "123", "1.5", "x1.5", "abc", "xabcz", "latest",
@@ -45,7 +51,8 @@ def _corpus(rng, n=400):
 
 class TestParityWithRe:
     @pytest.mark.parametrize("pattern",
-                             LIBRARY_PATTERNS + GENERATED_PATTERNS)
+                             LIBRARY_PATTERNS + GENERATED_PATTERNS
+                             + ICASE_PATTERNS)
     def test_search_parity(self, pattern):
         rng = random.Random(hash(pattern) & 0xffff)
         strs = _corpus(rng)
@@ -92,6 +99,9 @@ class TestParityWithRe:
         assert compile_dfa(r"(a)\1") is None          # backreference
         assert compile_dfa(r"(?=a)b") is None         # lookahead
         assert compile_dfa("a" * 600) is None         # state blowup
+        assert compile_dfa("(?m)^a") is None          # multiline anchors
+        assert compile_dfa("(?s)a.b") is None         # dot matches newline
+        assert compile_dfa("(?i)\u017f") is None      # folds to ASCII s
 
 
 class TestPrepIntegration:
