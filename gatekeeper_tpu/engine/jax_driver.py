@@ -1956,16 +1956,19 @@ class JaxDriver(LocalDriver):
 
     @locked
     def put_data_batch(self, target: str, entries) -> None:
-        # the parent method is itself @locked and the RW lock is not
-        # reentrant — call its unwrapped body under OUR writer hold
-        LocalDriver.put_data_batch.__wrapped__(self, target, entries)
-        st = self._state(target)
-        # keyed to the BATCH size, not the table size: a steady stream
-        # of small watch batches on a large table must not re-run
-        # mirror prep under the writer lock on every write
-        if isinstance(st, JaxTargetState) \
-                and len(entries) >= MIRROR_EAGER_MIN_ROWS:
-            self._materialize_mirror(st)
+        from gatekeeper_tpu.obs.trace import get_tracer
+        with get_tracer().span("store.put_batch", cat="store", root=True,
+                               rows=len(entries)):
+            # the parent method is itself @locked and the RW lock is not
+            # reentrant — call its unwrapped body under OUR writer hold
+            LocalDriver.put_data_batch.__wrapped__(self, target, entries)
+            st = self._state(target)
+            # keyed to the BATCH size, not the table size: a steady
+            # stream of small watch batches on a large table must not
+            # re-run mirror prep under the writer lock on every write
+            if isinstance(st, JaxTargetState) \
+                    and len(entries) >= MIRROR_EAGER_MIN_ROWS:
+                self._materialize_mirror(st)
 
     def _materialize_mirror(self, st) -> None:
         """Eagerly build what the first audit would otherwise build
@@ -2530,23 +2533,7 @@ class JaxDriver(LocalDriver):
                 st.installed_match = {}
                 st.fmt_cache = {}
 
-        # row ordering matches the scalar driver (sorted cache keys) so
-        # both drivers return identical result lists; the 1M-row sort +
-        # index dict are keyed on key_generation — pure updates (the
-        # dominant churn in a live cluster) never re-sort
         m = self.metrics
-        _tphase = _time.perf_counter()
-
-        def _phase(name):
-            # wall-clock audit phase timers: order/prep+dispatch-submit,
-            # handle-resolve (device upload+exec+compile wait), format
-            nonlocal _tphase
-            now = _time.perf_counter()
-            m.timer(name).observe(now - _tphase)
-            _tphase = now
-
-        ordered_rows, row_order = self._ensure_order(st)
-        rank = self._row_rank(st, row_order)
         # sweep root span, entered manually so the 300-line pipeline
         # body below keeps its indentation; closed in the finally.
         # Child spans on pool threads parent via _sweep_ctx (context
@@ -2554,11 +2541,24 @@ class JaxDriver(LocalDriver):
         from gatekeeper_tpu.obs.trace import get_tracer as _get_tracer
         _tracer = _get_tracer()
         _sweep_cm = _tracer.span("audit.sweep", cat="audit", target=target,
-                                 full=full, rows=len(ordered_rows))
+                                 full=full)
         _sweep_sp = _sweep_cm.__enter__()
         _sweep_ctx = _tracer.current()
         self.executor.sweep_active.set()
         try:
+            # row ordering matches the scalar driver (sorted cache keys)
+            # so both drivers return identical result lists; the sort +
+            # index dict are keyed on key_generation — pure updates
+            # never re-sort, a create or delete does
+            resorted = st.order_cache is None or \
+                st.order_cache[0] != st.table.key_generation
+            with _tracer.span("audit.order", cat="prep", parent=_sweep_ctx,
+                              resorted=resorted) as _osp:
+                ordered_rows, row_order = self._ensure_order(st)
+                rank = self._row_rank(st, row_order)
+            for _sp in (_sweep_sp, _osp):
+                if _sp is not None:
+                    _sp.args["rows"] = len(ordered_rows)
 
             # phase 1: dispatch every kind's device evaluation without
             # blocking — one packed-fetch round-trip per kind, all in
@@ -2590,45 +2590,64 @@ class JaxDriver(LocalDriver):
                 # match/rank gates ride bindings.arrays (_install_gates)
                 if mode not in ("topk", "mask"):
                     return None
+                ex = self.executor
                 if not full:
                     with _tracer.span("device.dispatch", cat="device",
                                       parent=_sweep_ctx, kind=kind,
-                                      mode=mode):
-                        return _launch(mode, prog, bindings)
+                                      mode=mode) as dsp:
+                        b0 = ex.thread_h2d_bytes()
+                        h = _launch(mode, prog, bindings)
+                        if dsp is not None:
+                            dsp.args["h2d_bytes"] = \
+                                ex.thread_h2d_bytes() - b0
+                        return h
                 # full sweep: meter the two device-side pipeline stages
                 # where they run (concurrently across kinds).
                 # stage_uploads enqueues this kind's H2D transfers as
                 # its own stage — the _arrays call inside run_*_async
                 # then hits the device cache — and block() rides until
-                # the result is device-resident, so device_s is
-                # per-kind device occupancy, not host-fetch wall (the
-                # D2H copy stays async and is collected in phase 2).
+                # the result is device-resident, so kind.block is host
+                # wall time until the device holds the result, not the
+                # host fetch (the D2H copy stays async and is collected
+                # in phase 2, under kind.fetch).
                 t0 = _time.perf_counter()
-                self.executor.stage_uploads(bindings)
+                with _tracer.span("kind.h2d", cat="h2d", parent=_sweep_ctx,
+                                  kind=kind) as hsp:
+                    b0 = ex.thread_h2d_bytes()
+                    ex.stage_uploads(bindings)
+                    staged = ex.thread_h2d_bytes() - b0
+                    if hsp is not None:
+                        hsp.args["h2d_bytes"] = staged
                 t1 = _time.perf_counter()
-                h = _launch(mode, prog, bindings).block()
+                with _tracer.span("kind.block", cat="device",
+                                  parent=_sweep_ctx, kind=kind, mode=mode):
+                    h = _launch(mode, prog, bindings).block()
                 t2 = _time.perf_counter()
-                _tracer.add_complete("kind.h2d", cat="h2d", t0=t0, t1=t1,
-                                     parent=_sweep_ctx, kind=kind)
-                _tracer.add_complete("kind.device", cat="device", t0=t1,
-                                     t1=t2, parent=_sweep_ctx, kind=kind,
-                                     mode=mode)
                 with ph_lock:
                     ph["h2d_s"] += t1 - t0
                     ph["device_s"] += t2 - t1
-                    ph["h2d_bytes"] += bindings.nbytes()
+                    ph["h2d_bytes"] += staged
                     per_kind_dev[kind] = \
                         per_kind_dev.get(kind, 0.0) + (t2 - t1)
                 return h
 
-            def _prep_done(kind, t0):
-                # close one kind's host-prep region: meter it into the
-                # pipeline phase sum and record the span
-                now = _time.perf_counter()
-                _tracer.add_complete("kind.host_prep", cat="host_prep",
-                                     t0=t0, t1=now, parent=_sweep_ctx,
-                                     kind=kind)
-                ph["host_prep_s"] += now - t0
+            # (t0, span) of the host-prep region open on the sweep thread
+            _prep = None
+
+            def _prep_begin(kind):
+                nonlocal _prep
+                _prep = (_time.perf_counter(), _tracer.begin(
+                    "kind.host_prep", cat="host_prep", parent=_sweep_ctx,
+                    kind=kind))
+
+            def _prep_done():
+                # close it: meter it into the pipeline phase sum and end
+                # the span
+                nonlocal _prep
+                t0, sp = _prep
+                _prep = None
+                _tracer.end(sp)
+                ph["host_prep_s"] += _time.perf_counter() - t0
 
             # prep + dispatch interleaved: each kind's device step is
             # submitted the moment its bindings are ready, so kind N's
@@ -2741,12 +2760,12 @@ class JaxDriver(LocalDriver):
             _t_pipe = _time.perf_counter()
             try:
                 with self._prep_lock:
-                    _tk = _time.perf_counter()
+                    _prep_begin("__axes_and_plan__")
                     self._prefetch_axes(st)
                     if full and not self.scalar_only and \
                             os.environ.get("GATEKEEPER_DEDUP", "on") != "off":
                         dedup_plan = self._audit_dedup_plan(st, target)
-                    _prep_done("__axes_and_plan__", _tk)
+                    _prep_done()
                     _sweep_kinds = sorted(st.templates)
                     if full and trace is None and not self.scalar_only:
                         # Stage-8 consumer 3: dispatch order packs
@@ -2770,11 +2789,11 @@ class JaxDriver(LocalDriver):
                                 self.supervisor.report_failure(
                                     "fault injection: device_lost "
                                     "mid-sweep")
-                        _tk = _time.perf_counter()
                         compiled = st.templates[kind]
                         constraints = self._kind_constraints(st, kind)
                         if not constraints:
                             continue
+                        _prep_begin(kind)
                         if pg_on and not full and trace is None:
                             reason = self._pages_ineligible(st, kind,
                                                             compiled)
@@ -2786,7 +2805,7 @@ class JaxDriver(LocalDriver):
                                 # ledger event order)
                                 spec = ("pages", kind, compiled,
                                         constraints, None, None, None)
-                                _prep_done(kind, _tk)
+                                _prep_done()
                                 futures.append(None)
                                 specs.append(spec)
                                 pg_kinds.append(kind)
@@ -2800,7 +2819,7 @@ class JaxDriver(LocalDriver):
                                 spec = (ent["mode"], kind, compiled,
                                         constraints, ent["prog"], bindings,
                                         ent["mask"])
-                                _prep_done(kind, _tk)
+                                _prep_done()
                                 f = concurrent.futures.Future()
                                 f.set_result(_ResolvedHandle(ent["payload"]))
                                 futures.append(f)
@@ -2826,7 +2845,7 @@ class JaxDriver(LocalDriver):
                                 # other template is unaffected
                                 self.metrics.counter(
                                     "external_data_kind_failures").inc()
-                                _prep_done(kind, _tk)
+                                _prep_done()
                                 continue
                             if bindings.f32_unsafe:
                                 # some bound numeric value does not survive a
@@ -2839,7 +2858,7 @@ class JaxDriver(LocalDriver):
                                 scalar_why[kind] = "f32 pin"
                                 spec = ("scalar", kind, compiled, constraints,
                                         None, None, mask)
-                                _prep_done(kind, _tk)
+                                _prep_done()
                                 futures.append(None)
                                 specs.append(spec)
                                 continue
@@ -2868,21 +2887,20 @@ class JaxDriver(LocalDriver):
                             if dedup_plan is not None and \
                                     kind in dedup_plan.rewritten:
                                 _t_dd = _time.perf_counter()
-                                prog2 = self._apply_dedup(
-                                    st, dedup_plan, kind, bindings,
-                                    dedup_shared_cols, dedup_applied)
+                                with _tracer.span("dedup.host_eval",
+                                                  cat="dedup",
+                                                  parent=_sweep_ctx,
+                                                  kind=kind):
+                                    prog2 = self._apply_dedup(
+                                        st, dedup_plan, kind, bindings,
+                                        dedup_shared_cols, dedup_applied)
                                 if prog2 is not None:
                                     prog = prog2
-                                _t_dd2 = _time.perf_counter()
-                                _tracer.add_complete(
-                                    "dedup.host_eval", cat="dedup",
-                                    t0=_t_dd, t1=_t_dd2,
-                                    parent=_sweep_ctx, kind=kind)
-                                dedup_host_s += _t_dd2 - _t_dd
+                                dedup_host_s += _time.perf_counter() - _t_dd
                             mode = "topk" if limit is not None else "mask"
                             spec = (mode, kind, compiled, constraints, prog,
                                     bindings, mask)
-                            _prep_done(kind, _tk)
+                            _prep_done()
                             if _twin_src is not None:
                                 tf = self._twin_future(
                                     _twin_src, mode, kind, prog, bindings,
@@ -2920,10 +2938,9 @@ class JaxDriver(LocalDriver):
                                 scalar_why[kind] = "below SMALL_WORKLOAD_EVALS"
                             spec = ("scalar", kind, compiled, constraints, None,
                                     None, mask)
-                            _prep_done(kind, _tk)
+                            _prep_done()
                             futures.append(None)
                         specs.append(spec)
-                _phase("audit_prep_submit")
 
                 # phase 2: resolve handles and host-format per kind.  The
                 # tag key (row rank, kind, constraint name) is a total
@@ -2939,12 +2956,30 @@ class JaxDriver(LocalDriver):
                 rcache: dict[int, tuple] = {}
                 tagged: list[tuple[tuple, Result]] = []
                 fmt_s = 0.0
+                fetch_s = 0.0
+
+                misses = m.counter("format_memo_misses")
 
                 def _format_kind(spec, handle):
+                    # kind.format: the fetch, then the capped rows
+                    # formatted; ``rows`` counts the (constraint, row)
+                    # pairs the scalar oracle re-evaluated (format-memo
+                    # misses; the sweep thread is the one formatting)
                     nonlocal fmt_s
+                    n0 = misses.value
+                    _tf = _time.perf_counter()
+                    with _tracer.span("kind.format", cat="format",
+                                      parent=_sweep_ctx, kind=spec[1],
+                                      mode=spec[0]) as fsp:
+                        _format_payload(spec, handle)
+                        if fsp is not None:
+                            fsp.args["rows"] = misses.value - n0
+                    fmt_s += _time.perf_counter() - _tf
+
+                def _format_payload(spec, handle):
+                    nonlocal fetch_s
                     mode, kind, compiled, constraints, prog, bindings, \
                         mask = spec
-                    _tf = _time.perf_counter()
                     # resolve the device payload once: the format path
                     # reads it through a pre-resolved handle, and a
                     # fresh (non-replayed) payload is captured for
@@ -2952,7 +2987,13 @@ class JaxDriver(LocalDriver):
                     payload = None
                     fresh = not isinstance(handle, _ResolvedHandle)
                     if handle is not None and mode in ("topk", "mask"):
-                        payload = handle.get()
+                        _tw = _time.perf_counter()
+                        with _tracer.span("kind.fetch", cat="device",
+                                          parent=_sweep_ctx, kind=kind):
+                            payload = handle.get()
+                        _wait = _time.perf_counter() - _tw
+                        m.timer("device_wait").observe(_wait)
+                        fetch_s += _wait
                         handle = _ResolvedHandle(payload)
                     try:
                         if mode == "pages":
@@ -2995,12 +3036,20 @@ class JaxDriver(LocalDriver):
                         if fp_enabled and fresh and payload is not None:
                             self._capture_sweep(st, kind, compiled, mode,
                                                 spec, payload, limit)
-                    _tf2 = _time.perf_counter()
-                    _tracer.add_complete("kind.format", cat="format",
-                                         t0=_tf, t1=_tf2,
-                                         parent=_sweep_ctx, kind=kind,
-                                         mode=mode)
-                    fmt_s += _tf2 - _tf
+
+                def _finished(fs):
+                    # those of ``fs`` done, else the first to finish: the
+                    # sweep thread waiting on the dispatch pool (the
+                    # executor's uploads and launches, and on a full
+                    # sweep the device)
+                    done = {f for f in fs if f.done()}
+                    if done:
+                        return done
+                    with _tracer.span("executor.wait", cat="device",
+                                      parent=_sweep_ctx):
+                        return concurrent.futures.wait(
+                            fs, return_when=concurrent.futures
+                            .FIRST_COMPLETED).done
 
                 if trace is None:
                     fut_idx = {f: i for i, f in enumerate(futures)
@@ -3008,23 +3057,27 @@ class JaxDriver(LocalDriver):
                     for i, f in enumerate(futures):
                         if f is None:   # scalar kinds: nothing to wait on
                             _format_kind(specs[i], None)
-                    for f in concurrent.futures.as_completed(fut_idx):
-                        _format_kind(specs[fut_idx[f]], f.result())
+                    pending = set(fut_idx)
+                    while pending:
+                        done = _finished(pending)
+                        pending -= done
+                        for f in done:
+                            _format_kind(specs[fut_idx[f]], f.result())
                 else:
                     for sp, f in zip(specs, futures):
+                        if f is not None:
+                            _finished({f})
                         _format_kind(sp,
                                      f.result() if f is not None else None)
-                # the resolve+format interleave is one wall region; split
-                # the timers so dispatch-wait stays device-side only
-                _now = _time.perf_counter()
-                m.timer("audit_dispatch_wait").observe(
-                    max(0.0, _now - _tphase - fmt_s))
-                m.timer("audit_format").observe(fmt_s)
-                _tphase = _now
+                # the wait for device results, and host format without it
+                m.timer("audit_dispatch_wait").observe(fetch_s)
+                m.timer("audit_format").observe(fmt_s - fetch_s)
                 ph["format_s"] = fmt_s
                 pipeline_wall = _time.perf_counter() - _t_pipe
             finally:
                 pool.shutdown(wait=False)
+                if _prep is not None:       # a kind's prep raised
+                    _tracer.end(_prep[1])
             tagged.sort(key=lambda kv: kv[0])
             # warm the churn-delta executables in the background: the first
             # sweep after data churn otherwise pays one serialized XLA
@@ -3555,9 +3608,7 @@ class JaxDriver(LocalDriver):
         import time as _time
         if handle is None:
             handle = self.executor.run_topk_async(prog, bindings, limit)
-        _tw = _time.perf_counter()
         counts, rows, valid = handle.get()
-        self.metrics.timer("device_wait").observe(_time.perf_counter() - _tw)
         _tf = _time.perf_counter()
         full_cand = None
         for ci, c in enumerate(constraints):

@@ -23,6 +23,7 @@ the reference, which recompiles every module on any PutModule
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any
 
@@ -156,19 +157,23 @@ class _LazyTwoTier:
                     break
             ev.wait()
         try:
-            lowered = lower()
+            from gatekeeper_tpu.obs.trace import get_tracer
+            role = self._raw.__name__
+            with get_tracer().span("executor.compile", cat="compile",
+                                   root=True, role=role, upgrade=False):
+                lowered = lower()
 
-            def install(full, _sig=sig):
-                self._fns[_sig] = full
+                def install(full, _sig=sig):
+                    self._fns[_sig] = full
 
-            if self._fast:
-                fn = self._ex._compile_two_tier(
-                    lowered, install,
-                    marker_key=(self._name, sig)
-                    if self._name is not None else None,
-                    upgrade=self._upgrade)
-            else:
-                fn = lowered.compile()
+                if self._fast:
+                    fn = self._ex._compile_two_tier(
+                        lowered, install, role,
+                        marker_key=(self._name, sig)
+                        if self._name is not None else None,
+                        upgrade=self._upgrade)
+                else:
+                    fn = lowered.compile()
             with self._lock:
                 self._fns[sig] = fn
             return fn
@@ -831,6 +836,11 @@ class ProgramExecutor:
         self.h2d_bytes = 0
         self.h2d_scatter_bytes = 0
         self.h2d_scatter_rows = 0
+        # the same bytes per thread (dirty-row indices of the delta
+        # path included): a span takes the difference around one call,
+        # which the executor-wide counters cannot give while the
+        # dispatch pool uploads for several kinds at once
+        self._h2d_local = __import__("threading").local()
         self._upgrade_q: list = []
         self._upgrade_thread = None
         # Stage-7 retrace sentinel (analysis/compilesurface.py): the
@@ -895,8 +905,8 @@ class ProgramExecutor:
         t.start()
         return t
 
-    def _compile_two_tier(self, lowered, install, marker_key=None,
-                           upgrade=True):
+    def _compile_two_tier(self, lowered, install, role: str,
+                          marker_key=None, upgrade=True):
         """Compile `lowered` fast; schedule the full-effort twin and
         hand it to `install(full_fn)` when ready.  Falls back to a
         single default-effort compile when the option is unsupported
@@ -933,7 +943,8 @@ class ProgramExecutor:
             # (the shared reduce, sharded top-k twins) need full effort.
             return fast
         with self._lock:
-            self._upgrade_q.append((_time.perf_counter(), lowered, install, h))
+            self._upgrade_q.append((_time.perf_counter(), lowered, install,
+                                    h, role))
             if self._upgrade_thread is None or \
                     not self._upgrade_thread.is_alive():
                 self._upgrade_thread = self.spawn_bg(
@@ -942,6 +953,7 @@ class ProgramExecutor:
 
     def _upgrade_loop(self):
         import time as _time
+        from gatekeeper_tpu.obs.trace import get_tracer
         from gatekeeper_tpu.utils.compile_cache import mark_upgraded
         while not self._shutdown.is_set():
             with self._lock:
@@ -951,8 +963,8 @@ class ProgramExecutor:
                 # quiesce-based deferral: wait until the whole cold
                 # flurry stopped enqueueing, so upgrades never compete
                 # with first-serve compiles for the serialized service
-                newest = max(t for t, _, _, _ in self._upgrade_q)
-                t_enq, lowered, install, h = self._upgrade_q[0]
+                newest = max(e[0] for e in self._upgrade_q)
+                _, lowered, install, h, role = self._upgrade_q[0]
             wait = newest + self.UPGRADE_DELAY_S - _time.perf_counter()
             if wait > 0 or self.sweep_active.is_set():
                 # never trace/compile under a live sweep — the jit
@@ -967,7 +979,9 @@ class ProgramExecutor:
                 # outside the lock and must still count as in-flight
                 self._upgrade_busy = getattr(self, "_upgrade_busy", 0) + 1
             try:
-                full = lowered.compile()
+                with get_tracer().span("executor.compile", cat="compile",
+                                       root=True, role=role, upgrade=True):
+                    full = lowered.compile()
                 install(full)
                 self.upgrades += 1
                 if h is not None:
@@ -1021,9 +1035,17 @@ class ProgramExecutor:
         d[id(self)] = bool(allowed) and self.mesh is not None and \
             self._mesh_divides(bindings.arrays)
 
+    def _staged(self, nbytes: int) -> None:
+        tl = self._h2d_local
+        tl.n = getattr(tl, "n", 0) + nbytes
+
+    def thread_h2d_bytes(self) -> int:
+        """Bytes the calling thread has staged to the device so far."""
+        return getattr(self._h2d_local, "n", 0)
+
     def _put(self, name: str, host: np.ndarray, sharded: bool) -> jax.Array:
-        self.h2d_bytes += int(host.nbytes)
         if sharded:
+            self._note_put(host)
             return jax.device_put(host, self._sharding_of(name))
         import os
         if host.dtype == np.int32 and host.size >= (1 << 16) and \
@@ -1043,9 +1065,14 @@ class ProgramExecutor:
                     # outgrowing the narrow range simply compiles the
                     # int32 twin once.
                     narrow = host.astype(dt)
-                    self.h2d_bytes += int(narrow.nbytes) - int(host.nbytes)
+                    self._note_put(narrow)
                     return jax.device_put(narrow)
+        self._note_put(host)
         return jax.device_put(host)
+
+    def _note_put(self, shipped: np.ndarray) -> None:
+        self.h2d_bytes += int(shipped.nbytes)
+        self._staged(int(shipped.nbytes))
 
     def _scatter_rows(self, name: str, dev: jax.Array, host: np.ndarray,
                       rows: np.ndarray, sharded: bool,
@@ -1080,6 +1107,7 @@ class ProgramExecutor:
                 return self._put(name, host, sharded)
         self.h2d_scatter_bytes += int(vals.nbytes) + int(rows.nbytes)
         self.h2d_scatter_rows += int(len(rows))
+        self._staged(int(vals.nbytes) + int(rows.nbytes))
         out = dev.at[tuple(idx)].set(jax.device_put(vals))
         if sharded:
             # scatter output placement follows XLA's choice; pin it back
@@ -1225,7 +1253,7 @@ class ProgramExecutor:
         if fn is None:
             self._guard_miss(program, arrays, delta_k=k)
 
-            def raw(args: tuple, old: jax.Array, pt: jax.Array):
+            def gk_devdelta(args: tuple, old: jax.Array, pt: jax.Array):
                 args = _widen_args(args)
                 d = dict(zip(names, args))
                 for nm, ex in ij_sig:
@@ -1242,16 +1270,23 @@ class ProgramExecutor:
                 count = jnp.sum(flat, dtype=jnp.int32)
                 return new, idx, signs, count, jnp.any(new, axis=0)
             with self._trace_lock:
-                fn = jax.jit(raw)
+                fn = jax.jit(gk_devdelta)
             with self._lock:
                 fn = self._cache.setdefault(key, fn)
                 self.compiles += 1
+            # jit compiles on its first call: that call is the compile
+            from gatekeeper_tpu.obs.trace import get_tracer
+            compiling = get_tracer().span("executor.compile", cat="compile",
+                                          root=True, role="gk_devdelta",
+                                          upgrade=False)
         else:
             with self._lock:
                 self.cache_hits += 1
+            compiling = contextlib.nullcontext()
         args = tuple(arrays[nm] for nm in names)
-        new_mask, idx, signs, count, row_any = fn(args, old_mask,
-                                                  page_table)
+        with compiling:
+            new_mask, idx, signs, count, row_any = fn(args, old_mask,
+                                                      page_table)
         return (new_mask, np.asarray(idx), np.asarray(signs),
                 int(count), np.asarray(row_any))
 
@@ -1349,38 +1384,44 @@ class ProgramExecutor:
                 raw = make_sharded_topk_packed(program, names, specs,
                                                self.mesh, topk, r_pad)
         elif topk is None:
-            def raw(args: tuple):
+            def gk_mask(args: tuple):
                 args = _widen_args(args)
                 return _eval_mask(program, dict(zip(names, args)))
+            raw = gk_mask
         else:
-            def raw(args: tuple):
+            def gk_topk(args: tuple):
                 args = _widen_args(args)
                 counts, rows, scores = _eval_topk(
                     program, dict(zip(names, args)), topk)
                 valid = (scores > 0).astype(jnp.int32)
                 return jnp.concatenate(
                     [counts[:, None], rows, valid], axis=1)  # [C, 1+2k]
+            raw = gk_topk
         example = tuple(
             jax.ShapeDtypeStruct(arrays[nm].shape, arrays[nm].dtype,
                                  sharding=arrays[nm].sharding
                                  if sharded else None)
             for nm in names)
         import time as _time
-        with self._trace_lock:
-            # tracing is GIL-bound; keep it serial (the pool would
-            # thrash), while compiles below run concurrently
+        from gatekeeper_tpu.obs.trace import get_tracer
+        with get_tracer().span("executor.compile", cat="compile", root=True,
+                               role=raw.__name__, upgrade=False):
+            with self._trace_lock:
+                # tracing is GIL-bound; keep it serial (the pool would
+                # thrash), while compiles below run concurrently
+                _t0 = _time.perf_counter()
+                lowered = jax.jit(raw).lower(example)
+                self.trace_seconds += _time.perf_counter() - _t0
             _t0 = _time.perf_counter()
-            lowered = jax.jit(raw).lower(example)
-            self.trace_seconds += _time.perf_counter() - _t0
-        _t0 = _time.perf_counter()
 
-        def install(full, _key=key):
-            with self._lock:
-                self._cache[_key] = full
+            def install(full, _key=key):
+                with self._lock:
+                    self._cache[_key] = full
 
-        fn = self._compile_two_tier(lowered, install, marker_key=key,
-                                     upgrade=(sharded or topk is not None))
-        self.compile_seconds += _time.perf_counter() - _t0
+            fn = self._compile_two_tier(
+                lowered, install, raw.__name__, marker_key=key,
+                upgrade=(sharded or topk is not None))
+            self.compile_seconds += _time.perf_counter() - _t0
         with self._lock:
             self._cache[key] = fn
             self.compiles += 1
@@ -1565,15 +1606,15 @@ class ProgramExecutor:
                 return pack(counts, rows, vals)
 
             if rank_shape is not None:
-                def raw(viol, rnk):
+                def gk_reduce(viol, rnk):
                     return reduce_chunked(viol, rnk)
             else:
-                def raw(viol):
+                def gk_reduce(viol):
                     return reduce_chunked(viol, None)
             # exec-critical and shared across kinds: always compile at
             # full effort (prewarm_reduce overlaps it with host prep);
             # a fast-compiled scan/top_k runs several times slower
-            fn = _LazyTwoTier(self, raw, fast=False)
+            fn = _LazyTwoTier(self, gk_reduce, fast=False)
             self._cache[key] = fn
         return fn
 
@@ -1584,7 +1625,7 @@ class ProgramExecutor:
         key = ("deltav", program.cache_key(), names, d_bucket)
         fn = self._cache.get(key)
         if fn is None:
-            def raw(viol_old, dirty, *args):
+            def gk_delta(viol_old, dirty, *args):
                 args = _widen_args(args)
                 full = dict(zip(names, args))
                 sliced = {}
@@ -1600,7 +1641,7 @@ class ProgramExecutor:
             # belongs to the scan/top_k class that executes several
             # times slower at low optimization effort (churn sweep
             # 0.58s -> 3.8s measured when left fast-final)
-            fn = _LazyTwoTier(self, raw, name=key)
+            fn = _LazyTwoTier(self, gk_delta, name=key)
             self._cache[key] = fn
         return fn
 
@@ -1625,6 +1666,7 @@ class ProgramExecutor:
             # prewarm_deltas examples (x64-off device_put would narrow
             # int64 anyway; being explicit keeps the cache warm even if
             # that config changes)
+            self._staged(int(rows.nbytes))
             viol = self._delta_fn(program, names, b)(
                 viol_old, jax.device_put(rows),
                 *(arrays[nm] for nm in names))

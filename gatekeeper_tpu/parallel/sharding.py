@@ -178,7 +178,7 @@ def make_sharded_topk_packed(program: Program, names: tuple[str, ...],
                              r_pad: int):
     """Unjitted shard-mapped capped audit packing (counts, rows, valid)
     into ONE [C, 1+2k] int32 array — the multi-chip twin of the
-    executor's single-device topk raw fn (one fetch per kind).
+    executor's single-device ``gk_topk`` (one fetch per kind).
 
     On a mesh spanning processes the packed result is additionally
     all_gathered over `c` so the output is fully replicated: every
@@ -202,16 +202,16 @@ def make_sharded_topk_packed(program: Program, names: tuple[str, ...],
                         out_specs=P(None, None) if spans else P("c", None),
                         check_vma=False)
 
-    def raw(args: tuple):
+    def gk_topk(args: tuple):
         return stepped(*args)
-    return raw
+    return gk_topk
 
 
 def make_sharded_mask_fn(program: Program, names: tuple[str, ...],
                          specs: dict[str, P], mesh: Mesh):
     """Unjitted shard-mapped full violation mask [C, R] (sharded over
-    both mesh axes) — the multi-chip twin of the executor's mask-mode
-    raw fn (the capped path's under-fill fallback).
+    both mesh axes) — the multi-chip twin of the executor's ``gk_mask``
+    (the capped path's under-fill fallback).
 
     On a process-spanning mesh the mask is all_gathered to full
     replication so every rank can fetch it locally — acceptable for
@@ -232,9 +232,9 @@ def make_sharded_mask_fn(program: Program, names: tuple[str, ...],
                         out_specs=P(None, None) if spans else P("c", "r"),
                         check_vma=False)
 
-    def raw(args: tuple):
+    def gk_mask(args: tuple):
         return stepped(*args)
-    return raw
+    return gk_mask
 
 
 def run_sharded_audit(program: Program, bindings: Bindings, mesh: Mesh,
