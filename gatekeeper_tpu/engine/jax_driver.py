@@ -35,6 +35,7 @@ rides the audit kernels via webhook batching (pkg webhook).
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import os
 from typing import Any
@@ -93,6 +94,67 @@ class _RowOrder:
         if p < 0:
             raise KeyError(row)
         return int(p)
+
+
+ORDER_NUMPY_MIN_ROWS = 65_536
+"""Above this many keys the audit row order is sorted with numpy and
+its sorted keys are kept, so that later key changes merge into them; at
+or below it a Python sort and a row -> position dict."""
+
+
+@dataclasses.dataclass
+class _OrderCache:
+    """The audit row order of one table at one (remap_generation,
+    key_generation).  On the numpy path ``keys`` holds the sorted keys
+    (a list of the table's own strings) and ``rows`` their rows, for the
+    next merge; ``merged`` counts the changed keys merged in to build
+    it (0 for a full sort)."""
+    table: Any
+    remap: int
+    kgen: int
+    ordered_rows: list
+    row_order: Any
+    keys: list | None = None
+    rows: np.ndarray | None = None
+    merged: int = 0
+
+    def holds(self, table) -> bool:
+        return (self.table is table
+                and self.remap == table.remap_generation
+                and self.kgen == table.key_generation)
+
+
+def _merge_sorted_keys(keys: list, ordered_rows: list, rows: np.ndarray,
+                       changed, lookup) -> tuple[list, list, np.ndarray]:
+    """``keys`` (sorted), ``ordered_rows`` and ``rows`` with the
+    ``changed`` keys applied: each is dropped where ``keys`` holds it
+    and inserted with its current row where ``lookup`` finds it now, so
+    a delete-then-recreate, a reused free row and a key created and
+    deleted in between all come out as a fresh sort would give them.
+    One splice of each sequence, whatever the number of keys."""
+    k_out: list = []
+    o_out: list = []
+    r_out: list = []
+    prev = 0
+    for k in sorted(changed):
+        i = bisect.bisect_left(keys, k)
+        row = lookup(k)
+        held = i < len(keys) and keys[i] == k
+        if not held and row is None:
+            continue
+        k_out += keys[prev:i]
+        o_out += ordered_rows[prev:i]
+        r_out.append(rows[prev:i])
+        prev = i + 1 if held else i
+        if row is not None:
+            k_out.append(k)
+            o_out.append(row)
+            r_out.append(np.array([row], dtype=np.int64))
+    k_out += keys[prev:]
+    o_out += ordered_rows[prev:]
+    r_out.append(rows[prev:])
+    return k_out, o_out, np.concatenate(r_out)
+
 
 SMALL_WORKLOAD_EVALS = 20_000
 """Below this many (resource, constraint) pairs per kind, the kind runs
@@ -176,7 +238,7 @@ class JaxTargetState(TargetState):
         # never overwrite it in place
         self.installed_match: dict[str, object] = {}
         self.rank_cache: tuple | None = None       # (generation, rank arr)
-        self.order_cache: tuple | None = None      # (gen, ordered_rows, row_order)
+        self.order_cache: _OrderCache | None = None
         self.fmt_cache: dict[str, tuple] = {}      # kind -> (con_ver, {(cname,row): (ver, results)})
         self.match_engine = None
         # kind -> Stage-5 dependency footprint (analysis/footprint.py)
@@ -1799,28 +1861,48 @@ class JaxDriver(LocalDriver):
         return 0
 
     def _ensure_order(self, st):
-        """Sorted-cache-key row order (matches the scalar driver) with
-        its key_generation-keyed cache; pure updates never re-sort."""
-        kgen = st.table.key_generation
-        if st.order_cache is not None and st.order_cache[0] == kgen:
-            _, ordered_rows, row_order = st.order_cache
-            return ordered_rows, row_order
-        items = list(st.table.rows_items())
-        if len(items) > 65536:
+        """Sorted-cache-key row order (matches the scalar driver), cached
+        per table, remap_generation and key_generation; pure updates
+        never re-order.  Above ORDER_NUMPY_MIN_ROWS keys, a key change
+        the table's key log still covers (``keys_changed_since``) is
+        merged into the cached sorted keys; otherwise, after a
+        wipe or compact, past the log's bound, on another table or at
+        or below that size, the keys are sorted from scratch.  Both
+        give the same order."""
+        table = st.table
+        cache = st.order_cache
+        if cache is not None and cache.holds(table):
+            return cache.ordered_rows, cache.row_order
+        remap, kgen = table.remap_generation, table.key_generation
+        if len(table) <= ORDER_NUMPY_MIN_ROWS:
+            ordered_rows = [row for _, row in sorted(table.rows_items())]
+            built = _OrderCache(table, remap, kgen, ordered_rows,
+                                {row: i for i, row in enumerate(ordered_rows)})
+            st.order_cache = built
+            return built.ordered_rows, built.row_order
+        changed = None
+        if cache is not None and cache.keys is not None \
+                and cache.table is table and cache.remap == remap:
+            changed = table.keys_changed_since(cache.kgen)
+        if changed is not None:
+            keys, ordered_rows, rows = _merge_sorted_keys(
+                cache.keys, cache.ordered_rows, cache.rows, changed,
+                table.lookup)
+        else:
             # numpy lexicographic sort of the key strings: ~4s of
             # Python tuple-sort at 1M rows becomes ~0.5s
-            keys = np.array([k for k, _ in items])
-            rows_arr = np.fromiter((r for _, r in items),
-                                   dtype=np.int64, count=len(items))
-            order = np.argsort(keys, kind="stable")
-            ordered_np = rows_arr[order]
-            ordered_rows = ordered_np.tolist()
-            row_order = _RowOrder(ordered_np)
-        else:
-            ordered_rows = [row for _, row in sorted(items)]
-            row_order = {row: i for i, row in enumerate(ordered_rows)}
-        st.order_cache = (kgen, ordered_rows, row_order)
-        return ordered_rows, row_order
+            items = list(table.rows_items())
+            key_list = [k for k, _ in items]
+            order = np.argsort(np.array(key_list), kind="stable")
+            rows = np.fromiter((r for _, r in items),
+                               dtype=np.int64, count=len(items))[order]
+            keys = [key_list[i] for i in order.tolist()]
+            ordered_rows = rows.tolist()
+        built = _OrderCache(table, remap, kgen, ordered_rows,
+                            _RowOrder(rows), keys, rows,
+                            len(changed) if changed is not None else 0)
+        st.order_cache = built
+        return built.ordered_rows, built.row_order
 
     def _prefetch_axes(self, st) -> None:
         """Union-prefetch the element-axis extractions: kinds sharing
@@ -2547,15 +2629,19 @@ class JaxDriver(LocalDriver):
         self.executor.sweep_active.set()
         try:
             # row ordering matches the scalar driver (sorted cache keys)
-            # so both drivers return identical result lists; the sort +
-            # index dict are keyed on key_generation — pure updates
-            # never re-sort, a create or delete does
+            # so both drivers return identical result lists; the order
+            # is keyed on key_generation — pure updates never re-order,
+            # a create or delete merges its key in (``merged`` keys) or
+            # re-sorts
             resorted = st.order_cache is None or \
-                st.order_cache[0] != st.table.key_generation
+                not st.order_cache.holds(st.table)
             with _tracer.span("audit.order", cat="prep", parent=_sweep_ctx,
                               resorted=resorted) as _osp:
                 ordered_rows, row_order = self._ensure_order(st)
                 rank = self._row_rank(st, row_order)
+                if _osp is not None:
+                    _osp.args["merged"] = \
+                        st.order_cache.merged if resorted else 0
             for _sp in (_sweep_sp, _osp):
                 if _sp is not None:
                     _sp.args["rows"] = len(ordered_rows)

@@ -42,6 +42,11 @@ def page_rows_env() -> int:
     except ValueError:
         return DEFAULT_PAGE_ROWS
 
+KEY_LOG_MIN = 4096
+"""The key-change log holds this many keys, or an eighth of the rows if
+that is more; past it the older half is dropped, and a consumer whose
+last look is older sorts from scratch."""
+
 PATH_DIFF_DEPTH = 6
 """Replace-diff recursion depth; deeper changes report the subtree."""
 
@@ -121,8 +126,16 @@ class ResourceTable:
         self.remap_generation = 0
         # bumped only when the key set changes (insert of a new key,
         # remove, wipe, compact) — pure updates keep sorted-key order
-        # caches (audit row order/rank) valid
+        # caches (audit row order/rank) valid.  Each insert or remove
+        # appends its key to _key_log, so _key_log[i] is the key of bump
+        # _key_log_base + i + 1: a sorted-key cache built at an older
+        # key_generation merges those keys in (keys_changed_since)
+        # instead of re-sorting.  wipe and compact reset the log, and
+        # past KEY_LOG_MIN its older half is dropped: a cache older
+        # than _key_log_base sorts from scratch.
         self.key_generation = 0
+        self._key_log: list[str] = []
+        self._key_log_base = 0
         self._ns_rows: set[int] = set()      # rows holding v1/Namespace
         self.ns_generation = 0               # last change to any ns row
         self._ns_touched = False
@@ -195,7 +208,7 @@ class ResourceTable:
                 self._metas.append(meta)
                 self._ensure_ver(row + 1)
             self._rows[key] = row
-            self.key_generation += 1
+            self._log_key(key)
         else:
             old_obj, old_meta = self._objs[row], self._metas[row]
             if old_meta != meta:
@@ -220,6 +233,32 @@ class ResourceTable:
             self._ns_rows.discard(row)
             self._ns_touched = True
         return row
+
+    def _log_key(self, key: str) -> None:
+        """Bump key_generation for a key inserted or removed."""
+        self.key_generation += 1
+        self._key_log.append(key)
+        n = len(self._key_log)
+        if n > KEY_LOG_MIN and n > len(self._objs) // 8:
+            drop = n // 2
+            del self._key_log[:drop]
+            self._key_log_base += drop
+
+    def _reset_key_log(self) -> None:
+        """Bump key_generation for a wipe or compact: no key list spans
+        it, so every older sorted-key cache sorts from scratch."""
+        self.key_generation += 1
+        self._key_log.clear()
+        self._key_log_base = self.key_generation
+
+    def keys_changed_since(self, kgen: int) -> set[str] | None:
+        """Keys inserted or removed after key_generation ``kgen`` (some
+        may be back, or gone again), or None when the log no longer
+        reaches back to ``kgen``: trimmed past its bound, or a wipe or
+        compact since."""
+        if not self._key_log_base <= kgen <= self.key_generation:
+            return None
+        return set(self._key_log[kgen - self._key_log_base:])
 
     def _flush_paths(self) -> None:
         if self._pending_paths or self._pending_pages:
@@ -288,7 +327,7 @@ class ResourceTable:
             self._ns_rows.discard(row)
             self.ns_generation = self.generation + 1
         self.generation += 1
-        self.key_generation += 1
+        self._log_key(key)
         self._ver[row] = self.generation
         self._flush_paths()
         if len(self._free) > 64 and len(self._free) > len(self._rows):
@@ -312,7 +351,7 @@ class ResourceTable:
         self._pending_kinds.clear()
         self.generation += 1
         self.remap_generation += 1
-        self.key_generation += 1
+        self._reset_key_log()
         self._path_floor = self.generation
         self.ns_generation = self.generation
 
@@ -331,7 +370,7 @@ class ResourceTable:
         self._pending_kinds.clear()
         self.generation += 1
         self.remap_generation += 1
-        self.key_generation += 1
+        self._reset_key_log()
         self._path_floor = self.generation
         self.ns_generation = self.generation
         self._ns_rows = {row for row, m in enumerate(new_metas)
