@@ -172,7 +172,7 @@ def _spec_sig(spec) -> tuple:
         tuple((d.name, d.src, d.pattern) for d in getattr(spec, "dfas", ())),
         tuple((m.name, m.cset, m.keys_path) for m in spec.membs),
         tuple((k.name, k.path) for k in spec.keyed_vals),
-        tuple((e.name, e.cset, e.axis) for e in spec.elem_keys),
+        tuple((e.name, e.cset, e.axis, e.rel) for e in spec.elem_keys),
         tuple((j.name, j.kind, j.inv_path, j.src_path,
                j.exclude_same_name, j.namespaced_only)
               for j in spec.inv_joins),

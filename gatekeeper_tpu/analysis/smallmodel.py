@@ -270,7 +270,10 @@ def derive_plan(lowered, constraints: list[dict],
             variants.append({keys[0]: {"t": 1}})
             variants.append({keys[0]: False})
             variants.append({k: {"t": 1} for k in keys})
-        add(Slot(kind="elemkeys", path=(), domain=tuple(variants),
+        if ek.rel:
+            # the keyed collection under the element: absent, or null
+            variants += [ABSENT, None]
+        add(Slot(kind="elemkeys", path=ek.rel, domain=tuple(variants),
                  default=0, axis=ek.axis))
 
     ordered = [slots[k] for k in sorted(slots, key=repr)]
@@ -400,7 +403,9 @@ def _build_resource(plan: ModelPlan, choice: dict[int, int],
                 idx = (choice.get(si, s.default) + e) % len(s.domain)
                 v = s.domain[idx]
                 if s.kind == "elemkeys":
-                    if isinstance(v, dict):
+                    if s.path:
+                        _assign_path(elem, s.path, v)
+                    elif isinstance(v, dict):
                         elem.update(v)
                 elif s.kind == "elem":
                     _assign_path(elem, s.path, v)

@@ -3282,13 +3282,16 @@ class JaxDriver(LocalDriver):
             # program ran on the device this sweep, and why each kind
             # that did not ran on the scalar oracle (replayed and paged
             # kinds are counted by the footprint and pages stanzas)
-            self.last_sweep_phases["dispatch"] = {
+            disp = self.last_sweep_phases["dispatch"] = {
                 "device": sorted(
                     sp[1] for sp in specs if sp[0] in ("topk", "mask")
                     and sp[1] not in fp_skipped
                     and sp[1] not in twin_shared),
                 "scalar": dict(sorted(scalar_why.items())),
             }
+            if _sweep_sp is not None:
+                _sweep_sp.args["templates_device"] = len(disp["device"])
+                _sweep_sp.args["templates_scalar"] = len(disp["scalar"])
             # plan-driven sharding stanza (both sweep shapes): mesh
             # size, which kinds ran sharded vs pinned replicated, the
             # per-shard evaluation slice and the collective count the

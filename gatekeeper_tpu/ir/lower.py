@@ -1270,6 +1270,13 @@ class Lowerer:
             for n in e.names:
                 self.env.pop(n, None)
             return
+        if lit.negated and not isinstance(e, (Compare, Assign)):
+            # before the deps: a keyed path under the element
+            # (limits[field]) is this recognizer's alone
+            ekn = self._try_elem_key_missing(e)
+            if ekn is not None:
+                self.conjuncts.append(ekn)
+                return
         # constant / constraint-only literals: fold or host-evaluate
         d = self._lit_deps(lit, frozenset())
         for v in list(d.env_vars):
@@ -1289,11 +1296,6 @@ class Lowerer:
             self.conjuncts.append(self._emit("not", (nid,)) if lit.negated else nid)
             return
         # plain term statement
-        if lit.negated:
-            ekn = self._try_elem_key_missing(e)
-            if ekn is not None:
-                self.conjuncts.append(ekn)
-                return
         sym = self._lower_value(e)
         nid = self._as_conjunct(sym, negated=lit.negated)
         if nid is not None:
@@ -1492,20 +1494,26 @@ class Lowerer:
         """``not <elem>[<probe>]`` with probe := params[_] — fires iff
         SOME required key fails the coll[key] statement for the element
         (the K8sRequiredProbes pattern; `not` applies per generator
-        binding of probe).  Exact for every element type: prep mirrors
-        the oracle's coll[key] semantics (dict -> truthy string key,
-        list -> truthy int index, other -> undefined); the device does
-        a B x ~ekm matmul over the key axis.  This node is consumed
-        directly as a conjunct — it must NOT be re-negated (that would
-        need the all-keys-present dual, not `not` of this node)."""
-        if not (isinstance(e, Ref) and isinstance(e.base, Var)
-                and len(e.path) == 1 and isinstance(e.path[0], Var)):
+        binding of probe).  The keyed collection may sit under a static
+        path of the element (``not container.resources.limits[field]``,
+        K8sRequiredResources): ``coll`` is then that path's value, and
+        an element without it has no keys.  Exact for every element
+        type: prep mirrors the oracle's coll[key] semantics (dict ->
+        truthy string key, list -> truthy int index, other ->
+        undefined); the device does a B x ~ekm matmul over the key
+        axis.  This node is consumed directly as a conjunct — it must
+        NOT be re-negated (that would need the all-keys-present dual,
+        not `not` of this node)."""
+        if not (isinstance(e, Ref) and isinstance(e.base, Var) and e.path
+                and isinstance(e.path[-1], Var)
+                and all(isinstance(p, Scalar) and isinstance(p.value, str)
+                        for p in e.path[:-1])):
             return None
         esym = self.env.get(e.base.name)
         if not (isinstance(esym, SLeaf) and esym.leaf.root not in ("obj", "meta")
                 and esym.leaf.path == ()):
             return None
-        ksym = self.env.get(e.path[0].name)
+        ksym = self.env.get(e.path[-1].name)
         if not isinstance(ksym, SCIter):
             return None
         axis = esym.leaf.root
@@ -1515,7 +1523,8 @@ class Lowerer:
         csname = self._make_cset(ksym.term, ksym.env_vars, iterate=True,
                                  encode="str")
         ekname = f"ek{next(self.serial)}"
-        self.elem_keys.append(ElemKeysReq(ekname, csname, axis))
+        self.elem_keys.append(ElemKeysReq(
+            ekname, csname, axis, tuple(p.value for p in e.path[:-1])))
         nid = self._emit("elem_keys_missing", (), (csname, ekname))
         # the node is existential over the probe bindings: negating it
         # computes all-present, NOT per-binding not-not — any enclosing

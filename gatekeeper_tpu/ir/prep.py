@@ -367,15 +367,18 @@ class KeyedValReq:
 @dataclasses.dataclass(frozen=True)
 class ElemKeysReq:
     """Element-axis truthy-key membership vs a per-constraint key set
-    (``not container[probe]`` with probe := params[_]).
+    (``not container[probe]`` with probe := params[_], or under a
+    static path of the element, ``not container.resources.limits[f]``).
 
     keys come from the paired cset (re-indexed local like MembReq);
     output ``ekm`` [K_pad, r_pad, e_pad] bool: key k present AND not
-    literal false in element (r, e) of the axis."""
+    literal false in the collection at ``rel`` of element (r, e) of the
+    axis (the element itself when ``rel`` is empty)."""
 
     name: str
     cset: str
     axis: str
+    rel: tuple[str, ...] = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -457,6 +460,51 @@ def _elem_rows(obj: Any, base: tuple[str, ...]):
         if isinstance(v, list):
             out.extend(v)
     return out
+
+
+def _elem_key_ids(needed: list, local: dict, interner: Interner):
+    """The needed keys of an ElemKeysReq by type: ({string key: local
+    index}, {int key: local index}); keys of any other type match
+    nothing."""
+    str_local: dict = {}
+    int_local: dict = {}
+    for gid in needed:
+        ks = interner.string(gid)
+        k = decode_value(ks) if ks.startswith("\x00") else ks
+        if isinstance(k, str):
+            str_local[k] = local[gid]
+        elif isinstance(k, int) and not isinstance(k, bool):
+            int_local[k] = local[gid]
+    return str_local, int_local
+
+
+def _fill_elem_keys(ekm: np.ndarray, rows, objs, base: tuple[str, ...],
+                    rel: tuple[str, ...], str_local: dict,
+                    int_local: dict) -> None:
+    """Set ``ekm[k, row, e]`` where needed key k passes the oracle's
+    ``coll[key]`` statement, ``coll`` being the value at ``rel`` of
+    element e of ``base``: dict -> string key present and not false;
+    list -> int (non-bool) index in range and element not false; any
+    other value, or no value at ``rel``, has no keys."""
+    e_pad = ekm.shape[2]
+    for row, o in zip(rows, objs):
+        if o is None:
+            continue
+        for ei, coll in enumerate(_elem_rows(o, base)):
+            if ei >= e_pad:
+                break
+            for p in rel:
+                coll = coll.get(p) if isinstance(coll, dict) else None
+            if isinstance(coll, dict):
+                # O(|needed|): probe the tiny key map against the
+                # collection, not the other way around
+                for k, li in str_local.items():
+                    if k in coll and coll[k] is not False:
+                        ekm[li, row, ei] = True
+            elif isinstance(coll, list):
+                for k, li in int_local.items():
+                    if 0 <= k < len(coll) and coll[k] is not False:
+                        ekm[li, row, ei] = True
 
 
 def build_elem_arrays(objs: list, base: tuple[str, ...], rels: list[tuple[tuple[str, ...], str]],
@@ -1028,35 +1076,25 @@ def build_bindings(spec: PrepSpec, table: ResourceTable,
             # dict -> string key present and not false; list -> int
             # (non-bool) index in range and element not false; any other
             # element type has no keys (coll[key] undefined).
-            e_pad = e_pads[ek.axis]
             k_pad = bucket(max(len(needed), 1), minimum=2)
-            ekm = np.zeros((k_pad, r_pad, e_pad), dtype=bool)
-            str_local: dict = {}
-            int_local: dict = {}
-            for gid in needed:
-                ks = interner.string(gid)
-                k = decode_value(ks) if ks.startswith("\x00") else ks
-                if isinstance(k, str):
-                    str_local[k] = local[gid]
-                elif isinstance(k, int) and not isinstance(k, bool):
-                    int_local[k] = local[gid]
-            base_path = dict(spec.axes)[ek.axis]
-            for row, o in enumerate(objs):
-                if o is None:
-                    continue
-                for ei, elem in enumerate(_elem_rows(o, base_path)):
-                    if ei >= e_pad:
-                        continue
-                    if isinstance(elem, dict):
-                        # O(|needed|): probe the tiny key map against
-                        # the element, not the other way around
-                        for k, li in str_local.items():
-                            if k in elem and elem[k] is not False:
-                                ekm[li, row, ei] = True
-                    elif isinstance(elem, list):
-                        for k, li in int_local.items():
-                            if 0 <= k < len(elem) and elem[k] is not False:
-                                ekm[li, row, ei] = True
+            ekm = np.zeros((k_pad, r_pad, e_pads[ek.axis]), dtype=bool)
+            base = axis_base[ek.axis]
+            str_local, int_local = _elem_key_ids(needed, local, interner)
+            # a string key on a dict is the truthy column of rel + (key,):
+            # served from the table's element-axis cache like any
+            # e-column; int keys index lists, which only the walk sees
+            rels = [(ek.rel + (k,), "truthy") for k in sorted(str_local)]
+            counts, cols = table.elem_arrays(base, rels)
+            offs = np.zeros((n + 1,), dtype=np.int64)
+            np.cumsum(counts, out=offs[1:])
+            idx_r, idx_e = _csr_to_dense_idx(counts, offs)
+            for rm in rels:
+                if len(cols[rm]):
+                    ekm[str_local[rm[0][-1]], idx_r, idx_e] = \
+                        np.asarray(cols[rm], dtype=bool)
+            if int_local:
+                _fill_elem_keys(ekm, range(n), objs, base, ek.rel, {},
+                                int_local)
             out[ek.name] = ekm
         if ek is not None or m is not None:
             if m is not None:
@@ -1468,31 +1506,8 @@ def update_bindings(spec: PrepSpec, table: ResourceTable,
         if ek is not None:
             ekm = cow(ek.name)
             ekm[:, dirty, :] = False
-            e_pad = prev.e_pads[ek.axis]
-            str_local: dict = {}
-            int_local: dict = {}
-            for gid in needed:
-                ks = interner.string(gid)
-                k = decode_value(ks) if ks.startswith("\x00") else ks
-                if isinstance(k, str):
-                    str_local[k] = local[gid]
-                elif isinstance(k, int) and not isinstance(k, bool):
-                    int_local[k] = local[gid]
-            base_path = axis_base[ek.axis]
-            for di, o in zip(dirty, dirty_objs):
-                if o is None:
-                    continue
-                for ei, elem in enumerate(_elem_rows(o, base_path)):
-                    if ei >= e_pad:
-                        continue
-                    if isinstance(elem, dict):
-                        for k, li in str_local.items():
-                            if k in elem and elem[k] is not False:
-                                ekm[li, di, ei] = True
-                    elif isinstance(elem, list):
-                        for k, li in int_local.items():
-                            if 0 <= k < len(elem) and elem[k] is not False:
-                                ekm[li, di, ei] = True
+            _fill_elem_keys(ekm, dirty, dirty_objs, axis_base[ek.axis],
+                            ek.rel, *_elem_key_ids(needed, local, interner))
 
     # ---- inventory joins: cross-row, so recompute and DIFF — the true
     # dirty set (rows whose join verdict changed) can exceed the table's

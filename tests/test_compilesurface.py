@@ -158,7 +158,7 @@ class TestAnalyzer:
         assert compilesurface.surface_digest(lowered) != before
 
     def test_scalar_surface_is_a_pin(self):
-        cert = compilesurface.scalar_surface("K8sRequiredResources")
+        cert = compilesurface.scalar_surface("K8sAgileLabels")
         assert cert.bounded and cert.scalar_pin
         assert cert.n_signatures == 0
 

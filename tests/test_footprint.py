@@ -14,6 +14,7 @@ oracle under churn.
 """
 
 import copy
+import os
 import random
 
 import pytest
@@ -27,6 +28,10 @@ from gatekeeper_tpu.ir.lower import lower_template
 from gatekeeper_tpu.library import all_docs, make_mixed
 from gatekeeper_tpu.store.table import ResourceMeta, ResourceTable
 from gatekeeper_tpu.target.k8s import K8sValidationTarget, TARGET_NAME
+
+AGILE_LABELS = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "demo", "agilebank", "templates", "k8sagilelabels.yaml")
 
 
 @pytest.fixture(autouse=True)
@@ -305,15 +310,15 @@ class TestValidation:
 class TestEngine:
     def test_cannot_lower_has_no_footprint(self):
         from tests.test_jax_driver import template_doc
+        import yaml
         jd = JaxDriver()
         c = Backend(jd).new_client([K8sValidationTarget()])
-        for tdoc, _cdoc in all_docs():
-            if tdoc["spec"]["crd"]["spec"]["names"]["kind"] \
-                    == "K8sRequiredResources":      # scalar fallback
-                c.add_template(tdoc)
+        # scalar fallback: sprintf with mixed dependencies
+        with open(AGILE_LABELS) as f:
+            c.add_template(yaml.safe_load(f))
         st = jd._state(TARGET_NAME)
-        assert st.templates["K8sRequiredResources"].vectorized is None
-        assert st.footprints.get("K8sRequiredResources") is None
+        assert st.templates["K8sAgileLabels"].vectorized is None
+        assert st.footprints.get("K8sAgileLabels") is None
 
     def test_mode_off_skips_analysis(self, monkeypatch):
         monkeypatch.setenv("GATEKEEPER_FOOTPRINT", "off")

@@ -572,3 +572,111 @@ violation[{"msg": msg}] {
         l = sorted(r.msg for r in local.audit().results())
         j = sorted(r.msg for r in jx.audit().results())
         assert l == j == ["missing 2"]   # ["x"] lacks index 2; index 0 ok
+
+
+# K8sRequiredResources' shape: a constraint-bound key under a static path
+# of the element (``not container.resources.limits[field]``)
+_RR_CONTAINERS = [
+    {"name": "none"},
+    {"name": "empty", "resources": {}},
+    {"name": "null", "resources": {"limits": None, "requests": None}},
+    {"name": "lists", "resources": {"limits": ["cpu"], "requests": [0]}},
+    {"name": "str", "resources": "cpu"},
+    {"name": "falsy", "resources": {"limits": {"cpu": False, "memory": 0},
+                                    "requests": {"cpu": "", "memory": None}}},
+    {"name": "full", "resources": {
+        "limits": {"cpu": "1", "memory": "1Gi"},
+        "requests": {"cpu": "500m", "memory": "1Gi"}}},
+    {"name": "cpu", "resources": {"limits": {"cpu": "1"},
+                                  "requests": {"cpu": "1"}}},
+]
+
+
+def _rr_pods():
+    pods = [{"apiVersion": "v1", "kind": "Pod",
+             "metadata": {"name": f"p-{c['name']}", "namespace": "d"},
+             "spec": {"containers": [c]}} for c in _RR_CONTAINERS]
+    pods.append({"apiVersion": "v1", "kind": "Pod",
+                 "metadata": {"name": "p-all", "namespace": "d"},
+                 "spec": {"containers": _RR_CONTAINERS}})
+    return pods
+
+
+class TestKeyedPathUnderElement:
+    _pair = TestNestedElementAxes._pair
+    _tdoc = TestNestedElementAxes._tdoc
+    _cdoc = TestNestedElementAxes._cdoc
+
+    def _audit_pair(self, params_by_name, pods, churn=()):
+        from gatekeeper_tpu.library import LIBRARY
+        local, jx = self._pair()
+        for c in (local, jx):
+            c.add_template(self._tdoc("K8sRequiredResources",
+                                      LIBRARY["K8sRequiredResources"][0]))
+            for name, params in params_by_name.items():
+                c.add_constraint(self._cdoc("K8sRequiredResources", name,
+                                            params))
+            for p in pods:
+                c.add_data(p)
+        st = jx.driver.state["admission.k8s.gatekeeper.sh"]
+        assert st.templates["K8sRequiredResources"].vectorized is not None
+
+        def results(c, **kw):
+            return sorted((r.constraint["metadata"]["name"],
+                           (r.review or {}).get("name"), r.msg)
+                          for r in c.audit(**kw).results())
+        want = results(local)
+        assert results(jx, full=True) == want
+        assert jx.driver.last_sweep_phases["dispatch"]["device"] \
+            == ["K8sRequiredResources"]
+        for p in churn:          # the delta path refills the dirty rows
+            for c in (local, jx):
+                c.add_data(p)
+        if churn:
+            want = results(local)
+            assert results(jx) == want
+        return want
+
+    @pytest.mark.parametrize("limits,requests", [
+        ([], []), (["cpu"], []), ([], ["memory"]),
+        (["cpu", "memory"], ["cpu"]), (None, ["cpu", "memory"])])
+    def test_key_lists_parity(self, limits, requests):
+        params = {"requests": requests}
+        if limits is not None:
+            params["limits"] = limits
+        got = self._audit_pair({"rr": params}, _rr_pods())
+        fired = len(limits or []) + len(requests)
+        assert bool(got) == bool(fired)
+        # keys present with any value but false pass the statement
+        assert not any(n in ("p-full",) and f"limits.{k}" in m
+                       for _, n, m in got for k in ("cpu", "memory"))
+
+    def test_device_mask_equals_oracle(self):
+        from gatekeeper_tpu.library import LIBRARY
+        check(LIBRARY["K8sRequiredResources"][0], "K8sRequiredResources",
+              [{"spec": {"parameters": {"limits": ["cpu", "memory"],
+                                        "requests": ["cpu"]}}},
+               {"spec": {"parameters": {"limits": [], "requests": []}}},
+               {"spec": {"parameters": {"limits": ["memory"]}}}],
+              _rr_pods())
+
+    def test_two_constraints_different_lists_and_churn(self):
+        fixed = {"apiVersion": "v1", "kind": "Pod",
+                 "metadata": {"name": "p-none", "namespace": "d"},
+                 "spec": {"containers": [_RR_CONTAINERS[6]]}}
+        broken = {"apiVersion": "v1", "kind": "Pod",
+                  "metadata": {"name": "p-full", "namespace": "d"},
+                  "spec": {"containers": [{"name": "full", "resources": {
+                      "limits": {"memory": "1Gi"}}}]}}
+        got = self._audit_pair(
+            {"rr-a": {"limits": ["cpu"], "requests": []},
+             "rr-b": {"limits": ["memory", "ephemeral-storage"],
+                      "requests": ["cpu"]}},
+            _rr_pods(), churn=[fixed, broken])
+        assert ("rr-a", "p-full",
+                "container <full> has no resources.limits.cpu") in got
+        assert ("rr-b", "p-full",
+                "container <full> has no resources.requests.cpu") in got
+        assert [(c, m) for c, n, m in got if n == "p-none"] == [
+            ("rr-b", "container <full> has no "
+                     "resources.limits.ephemeral-storage")]

@@ -4,6 +4,7 @@ rejected).  A regression that drops a template off the device engine —
 or an unsound widening that suddenly "lowers" a scalar template — must
 show up as a deliberate edit to lowering_buckets.json."""
 
+from gatekeeper_tpu.library import LIBRARY
 from gatekeeper_tpu.library.buckets import compute_buckets, load_committed
 
 
@@ -25,3 +26,10 @@ def test_no_template_silently_changes_bucket():
 def test_no_rejected_templates_in_corpus():
     assert not [k for k, v in compute_buckets().items()
                 if v.startswith("rejected")]
+
+
+def test_every_library_template_is_device_lowered():
+    committed = load_committed()
+    assert {k: committed[k] for k in LIBRARY
+            if committed[k] != "device-lowered"} == {}
+    assert len(LIBRARY) == 49

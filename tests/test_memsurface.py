@@ -175,7 +175,7 @@ class TestOverApprox:
             >= cert.resident_bytes({"c": 8, "r": 64})
 
     def test_scalar_pin_claims_nothing(self):
-        cert = memsurface.scalar_surface("K8sRequiredResources")
+        cert = memsurface.scalar_surface("K8sAgileLabels")
         assert cert.scalar_pin and cert.bounded
         assert cert.peak_bytes() == 0
         assert memsurface.budget_reason(cert) is None

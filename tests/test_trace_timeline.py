@@ -228,3 +228,27 @@ class TestBeginEnd:
         assert sp is None
         tr.end(sp)
         assert tr.export()["traceEvents"] == []
+
+
+def test_sweep_span_counts_device_and_scalar_templates(tracer, client):
+    import os
+
+    import yaml
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for sub in ("templates/k8sagilelabels.yaml",
+                "constraints/owner-must-be-provided.yaml"):
+        with open(os.path.join(root, "demo", "agilebank", sub)) as f:
+            doc = yaml.safe_load(f)
+        if doc["kind"] == "K8sAgileLabels":     # every kind, as Pods are
+            del doc["spec"]["match"]
+        (client.add_template if sub.startswith("templates")
+         else client.add_constraint)(doc)
+    client.audit(limit_per_constraint=20, full=True)
+    evs = tracer.export()["traceEvents"]
+    sweep, = [e["args"] for e in evs if e["name"] == "audit.sweep"]
+    assert (sweep["templates_device"], sweep["templates_scalar"]) \
+        == (len(KINDS), 1)
+    modes = {e["args"]["kind"]: e["args"]["mode"] for e in evs
+             if e["name"] == "kind.format"}
+    assert modes["K8sAgileLabels"] == "scalar"
+    assert {modes[k] for k in KINDS} == {"topk"}
