@@ -2717,21 +2717,26 @@ class JaxDriver(LocalDriver):
                         per_kind_dev.get(kind, 0.0) + (t2 - t1)
                 return h
 
-            # (t0, span) of the host-prep region open on the sweep thread
+            # (t0, span, table evaluations so far) of the host-prep
+            # region open on the sweep thread
+            from gatekeeper_tpu.ir.prep import TABLE_VALUES as _tv
             _prep = None
 
             def _prep_begin(kind):
                 nonlocal _prep
                 _prep = (_time.perf_counter(), _tracer.begin(
                     "kind.host_prep", cat="host_prep", parent=_sweep_ctx,
-                    kind=kind))
+                    kind=kind), _tv.batched, _tv.host)
 
             def _prep_done():
-                # close it: meter it into the pipeline phase sum and end
-                # the span
+                # close it: meter it into the pipeline phase sum, record
+                # the kind's table evaluations by path, end the span
                 nonlocal _prep
-                t0, sp = _prep
+                t0, sp, b0, h0 = _prep
                 _prep = None
+                if sp is not None:
+                    sp.args["table_values_batched"] = _tv.batched - b0
+                    sp.args["table_values_host"] = _tv.host - h0
                 _tracer.end(sp)
                 ph["host_prep_s"] += _time.perf_counter() - t0
 

@@ -49,7 +49,7 @@ from typing import Any, Callable
 
 from gatekeeper_tpu.ir.prep import (
     CSetReq, CValReq, DfaReq, EColReq, ElemKeysReq, InvJoinReq, KeyedValReq,
-    MembReq, PrepSpec, PTableReq, RColReq, TableReq)
+    LeafCall, MembReq, PrepSpec, PTableReq, RColReq, TableReq)
 from gatekeeper_tpu.ir.program import CMP_OPS, Node, Program, RuleSpec
 from gatekeeper_tpu.ops import regex_dfa
 from gatekeeper_tpu.rego import builtins as bi
@@ -914,7 +914,8 @@ class Lowerer:
         self.tables.append(TableReq(tname, src, fn, out=out, src_val=True,
                                     regex=regex,
                                     ext_providers=self._collect_ext_providers(
-                                        term)))
+                                        term),
+                                    leaf_call=self._leaf_call(term)))
         idx = self._emit_leaf(sym.leaf, "val")
         return self._emit("table", (idx,), (tname,))
 
@@ -952,6 +953,65 @@ class Lowerer:
                 return bi._glob_to_regex(term.args[0].value, delims)
         return None
 
+    def _pure_builtin(self, name: tuple[str, ...]) -> bool:
+        """name calls a registered builtin that gives equal results for
+        equal arguments (not shadowed by a template function)."""
+        return name in bi.REGISTRY and name not in bi.IMPURE_BUILTINS \
+            and not (len(name) == 1 and name[0] in self.interp.rules)
+
+    def _leaf_free(self, term: Term) -> bool:
+        """term evaluates alike wherever it is evaluated and never reads
+        the table's leaf: scalars, variables that name no rule,
+        references, collections, operators and pure builtin calls."""
+        if isinstance(term, Scalar):
+            return True
+        if isinstance(term, Var):
+            return term.name != "__leaf0__" \
+                and term.name not in self.interp.rules
+        if isinstance(term, Ref):
+            return self._leaf_free(term.base) \
+                and all(self._leaf_free(p) for p in term.path)
+        if isinstance(term, (ArrayTerm, SetTerm)):
+            return all(self._leaf_free(t) for t in term.items)
+        if isinstance(term, ObjectTerm):
+            return all(self._leaf_free(k) and self._leaf_free(v)
+                       for k, v in term.pairs)
+        if isinstance(term, BinOp):
+            return self._leaf_free(term.lhs) and self._leaf_free(term.rhs)
+        if isinstance(term, UnaryMinus):
+            return self._leaf_free(term.operand)
+        if isinstance(term, Call):
+            return self._pure_builtin(term.name) \
+                and all(self._leaf_free(a) for a in term.args)
+        return False
+
+    def _leaf_call(self, term: Term, pvar: str | None = None) \
+            -> LeafCall | None:
+        """The batched form of a table's leaf term: exactly one call to a
+        pure builtin with ``__leaf0__`` once, as a direct argument, and
+        every other argument leaf-free — a constant, or a function of the
+        ptable parameter ``pvar`` (``endswith(leaf, concat(":", ["",
+        tag]))``).  Prep evaluates the other arguments once per
+        parameter and calls the builtin directly per distinct value.
+        Any other term: None, and the table keeps the per-value walk."""
+        if not isinstance(term, Call) or not self._pure_builtin(term.name):
+            return None
+        pos = [i for i, a in enumerate(term.args)
+               if isinstance(a, Var) and a.name == "__leaf0__"]
+        if len(pos) != 1:
+            return None
+        others = term.args[:pos[0]] + term.args[pos[0] + 1:]
+        if not all(self._leaf_free(a) for a in others):
+            return None
+        interp = self.interp
+
+        def args(param, _t=ArrayTerm(others), _pv=pvar):
+            env = {} if _pv is None else {_pv: freeze(param)}
+            ctx = interp._ctx(UNDEFINED, None, None)
+            return [v for v, _ in interp._eval_term(ctx, _t, env)]
+
+        return LeafCall(bi.REGISTRY[term.name], pos[0], args)
+
     def _ptable_node(self, leaf: LeafId, pred_term: Term, pvar: str,
                      iter_term: Term, iter_env: tuple[str, ...],
                      mode: str = "any") -> int:
@@ -972,7 +1032,9 @@ class Lowerer:
                     return True
             return False
 
-        self.ptables.append(PTableReq(tname, src, cparams, fn, src_val=True))
+        self.ptables.append(PTableReq(tname, src, cparams, fn, src_val=True,
+                                      leaf_call=self._leaf_call(pred_term,
+                                                                pvar)))
         idx = self._emit_leaf(leaf, "val")
         op = "ptable_any" if mode == "any" else "ptable_all"
         return self._emit(op, (idx,), (tname, tname))

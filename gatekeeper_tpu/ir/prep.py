@@ -30,11 +30,13 @@ the reference instead recompiles every module on any change
 from __future__ import annotations
 
 import dataclasses
+import threading
 from typing import Any, Callable
 
 import numpy as np
 
 from gatekeeper_tpu.ir.encode import decode_value, encode_value
+from gatekeeper_tpu.rego.values import freeze
 from gatekeeper_tpu.store.columns import ColSpec, get_path, iter_path
 from gatekeeper_tpu.store.interner import Interner, MISSING
 from gatekeeper_tpu.store.table import ResourceTable
@@ -280,6 +282,10 @@ class TableReq:
     provider before running the per-value fn loop, so fn's
     external_data call is a cache hit — the "key-collection pass" of
     the two-phase prefetch/gather design.
+
+    leaf_call: set when fn is exactly one call to a pure builtin of the
+    value (``endswith(value, ":latest")``) — the build calls the
+    builtin directly per distinct value (LeafCall).
     """
 
     name: str
@@ -289,6 +295,8 @@ class TableReq:
     src_val: bool = False
     regex: str | None = None
     ext_providers: tuple[str, ...] = ()
+    leaf_call: LeafCall | None = dataclasses.field(
+        default=None, compare=False, hash=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -311,15 +319,32 @@ class DfaReq:
 
 
 @dataclasses.dataclass(frozen=True)
+class LeafCall:
+    """A table whose leaf term is exactly one call to a pure builtin:
+    ``fn(*others[:pos], leaf, *others[pos:])``.  ``args(param)`` lists
+    every tuple of the other arguments' values (param: the ptable
+    parameter, None for a unary table) as the interpreter enumerates
+    them.  The build then calls ``fn`` directly once per distinct value
+    instead of walking the term per value; results are identical."""
+
+    fn: Callable
+    pos: int
+    args: Callable[[Any], list]
+
+
+@dataclasses.dataclass(frozen=True)
 class PTableReq:
     """Parametric table: fn(value_string, param_string) -> bool, evaluated
-    for every distinct param across the constraint set."""
+    for every distinct param across the constraint set.  leaf_call: as
+    on TableReq."""
 
     name: str
     src: str
     cparams: Callable[[dict], list] = dataclasses.field(compare=False, hash=False)
     fn: Callable[[Any, Any], Any] = dataclasses.field(compare=False, hash=False)
     src_val: bool = False
+    leaf_call: LeafCall | None = dataclasses.field(
+        default=None, compare=False, hash=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -752,6 +777,131 @@ def _ext_prefetch(tr, uids, interner) -> None:
             rt.prefetch(provider, keys)
 
 
+class _TableValues(threading.local):
+    """Table evaluations made by this thread's builds, by path:
+    ``batched`` — a LeafCall's direct builtin calls and the regex DFA
+    batch; ``host`` — the per-value interpreter walk (``fn``).  A ptable
+    counts one evaluation per (parameter, value).  Running totals:
+    readers take differences (the ``kind.host_prep`` span args)."""
+
+    batched = 0
+    host = 0
+
+
+TABLE_VALUES = _TableValues()
+
+
+def _table_args(src_val: bool, uids: list, interner) -> list:
+    """The table's distinct source values, as its fn receives them."""
+    if not src_val:
+        return [interner.string(uid) for uid in uids]
+    return [decode_value(interner.string(uid)) for uid in uids]
+
+
+def _leaf_call_each(lc: LeafCall, values: list, param: Any,
+                    truth: bool) -> list:
+    """The leaf call over every (frozen) value, as the per-value
+    interpreter walk of the same term gives it through _eval_host: per
+    value the first defined result over the other arguments' value
+    tuples — with ``truth``, True when any is truthy — and None where
+    undefined."""
+    from gatekeeper_tpu.rego.builtins import UNDEFINED, BuiltinError
+    res: list = [None] * len(values)
+    arg_sets = _eval_host(lc.args, param) if values else None
+    if not arg_sets:
+        return res                 # an argument undefined or failed
+    fn, pos = lc.fn, lc.pos
+    todo = range(len(values))
+    for other in arg_sets:
+        pre, post = other[:pos], other[pos:]
+        left = []
+        for i in todo:
+            try:
+                v = fn(*pre, values[i], *post)
+            except (BuiltinError, TypeError, ValueError, KeyError,
+                    IndexError, ZeroDivisionError):
+                left.append(i)
+                continue
+            if v is UNDEFINED or (truth and v is False):
+                left.append(i)
+            elif truth:
+                res[i] = True
+            elif v is not None:
+                res[i] = v
+            # else a null: the walk returns it, and _eval_host reads
+            # it as undefined
+        todo = left
+    return res
+
+
+def _table_fill(tr: TableReq, uids: list, interner, ok: np.ndarray,
+                vals: np.ndarray) -> bool:
+    """Evaluate a unary table at ``uids`` into ``ok``/``vals``; True when
+    some numeric output does not survive float32."""
+    if not uids:
+        return False
+    if _regex_table_batch(tr, uids, interner, ok, vals):
+        TABLE_VALUES.batched += len(uids)
+        return False
+    _ext_prefetch(tr, uids, interner)
+    args = _table_args(tr.src_val, uids, interner)
+    if tr.leaf_call is not None:
+        res = _leaf_call_each(tr.leaf_call, [freeze(a) for a in args],
+                              None, tr.out == "bool")
+        TABLE_VALUES.batched += len(uids)
+    else:
+        res = [_eval_host(tr.fn, a) for a in args]
+        TABLE_VALUES.host += len(uids)
+    f32_unsafe = False
+    for uid, v in zip(uids, res):
+        if v is None:
+            continue
+        if tr.out == "num":
+            if isinstance(v, (int, float)) and not isinstance(v, bool):
+                ok[uid] = True
+                vals[uid] = np.float32(v)
+                f32_unsafe = f32_unsafe or not _f32_exact([v])
+        elif tr.out == "id_str":
+            if isinstance(v, str):
+                ok[uid] = True
+                vals[uid] = interner.intern(v)
+        elif tr.out == "id_val":
+            ekey = encode_value(v)
+            if ekey is not None:
+                ok[uid] = True
+                vals[uid] = interner.intern(ekey)
+        else:
+            ok[uid] = True
+            vals[uid] = bool(v) if isinstance(v, bool) else True
+    return f32_unsafe
+
+
+def _ptable_rows(pt: PTableReq, params: list, uids: list,
+                 interner) -> np.ndarray:
+    """[len(params), len(uids)] bool: fn(value, param) per distinct
+    parameter and value, each value decoded once."""
+    tbl = np.zeros((len(params), len(uids)), dtype=bool)
+    if not params or not uids:
+        return tbl
+    args = _table_args(pt.src_val, uids, interner)
+    lc = pt.leaf_call
+    frozen = [freeze(a) for a in args] if lc is not None else None
+    for pi, pstr in enumerate(params):
+        if lc is not None:
+            tbl[pi] = [v is True for v in
+                       _leaf_call_each(lc, frozen, pstr, True)]
+            continue
+        for u, arg in enumerate(args):
+            v = _eval_host(pt.fn, arg, pstr)
+            tbl[pi, u] = bool(v) if v is not None and v is not False else False
+    n = len(params) * len(uids)
+    if lc is not None:
+        TABLE_VALUES.batched += n
+    else:
+        TABLE_VALUES.host += n
+    return tbl
+
+
 def build_inv_join(req: InvJoinReq, table: ResourceTable,
                    r_pad: int) -> np.ndarray:
     """[r_pad] bool: the review row has a same-valued other object.
@@ -953,38 +1103,11 @@ def build_bindings(spec: PrepSpec, table: ResourceTable,
             vals = np.full((t_pad,), MISSING, dtype=np.int32)
         else:
             vals = np.zeros((t_pad,), dtype=bool)
-        if _regex_table_batch(tr, uniq.tolist(), interner, ok, vals):
-            out[tr.name + ".ok"] = ok
-            out[tr.name + ".v"] = vals
-            state["tables"][tr.name] = set(uniq.tolist())
-            continue
-        _ext_prefetch(tr, uniq.tolist(), interner)
-        for uid in uniq.tolist():
-            key = interner.string(uid)
-            arg = decode_value(key) if tr.src_val else key
-            v = _eval_host(tr.fn, arg)
-            if v is None:
-                continue
-            if tr.out == "num":
-                if isinstance(v, (int, float)) and not isinstance(v, bool):
-                    ok[uid] = True
-                    vals[uid] = np.float32(v)
-                    f32_unsafe = f32_unsafe or not _f32_exact([v])
-            elif tr.out == "id_str":
-                if isinstance(v, str):
-                    ok[uid] = True
-                    vals[uid] = interner.intern(v)
-            elif tr.out == "id_val":
-                ekey = encode_value(v)
-                if ekey is not None:
-                    ok[uid] = True
-                    vals[uid] = interner.intern(ekey)
-            else:
-                ok[uid] = True
-                vals[uid] = bool(v) if isinstance(v, bool) else True
+        uids = uniq.tolist()
+        f32_unsafe = _table_fill(tr, uids, interner, ok, vals) or f32_unsafe
         out[tr.name + ".ok"] = ok
         out[tr.name + ".v"] = vals
-        state["tables"][tr.name] = set(uniq.tolist())
+        state["tables"][tr.name] = set(uids)
 
     # ---- parametric tables, pre-combined per constraint
     #
@@ -1017,12 +1140,8 @@ def build_bindings(spec: PrepSpec, table: ResourceTable,
         vmap = np.full((t_pad,), u_pad - 1, dtype=np.int32)
         vmap[uniq] = np.arange(len(uniq), dtype=np.int32)
         tbl = np.zeros((len(distinct), u_pad), dtype=bool)
-        for pi, pstr in enumerate(distinct):
-            for u, uid in enumerate(uniq.tolist()):
-                key = interner.string(uid)
-                arg = decode_value(key) if pt.src_val else key
-                v = _eval_host(pt.fn, arg, pstr)
-                tbl[pi, u] = bool(v) if v is not None and v is not False else False
+        tbl[:, :len(uniq)] = _ptable_rows(pt, list(distinct), uniq.tolist(),
+                                          interner)
         t_any = np.zeros((c_pad, u_pad), dtype=bool)
         t_all = np.zeros((c_pad, u_pad), dtype=bool)
         for ci, lst in enumerate(per_con):
@@ -1408,33 +1527,8 @@ def update_bindings(spec: PrepSpec, table: ResourceTable,
             id_rows = np.asarray(sorted(new_ids), dtype=np.int64)
             append_rows[tr.name + ".ok"] = id_rows
             append_rows[tr.name + ".v"] = id_rows
-            if _regex_table_batch(tr, list(new_ids), interner, ok, vals):
-                state["tables"][tr.name] = evaluated | set(new_ids)
-                continue
-            _ext_prefetch(tr, new_ids, interner)
-            for uid in new_ids:
-                key = interner.string(uid)
-                arg = decode_value(key) if tr.src_val else key
-                v = _eval_host(tr.fn, arg)
-                if v is None:
-                    continue
-                if tr.out == "num":
-                    if isinstance(v, (int, float)) and not isinstance(v, bool):
-                        ok[uid] = True
-                        vals[uid] = np.float32(v)
-                        f32_unsafe = f32_unsafe or not _f32_exact([v])
-                elif tr.out == "id_str":
-                    if isinstance(v, str):
-                        ok[uid] = True
-                        vals[uid] = interner.intern(v)
-                elif tr.out == "id_val":
-                    ekey = encode_value(v)
-                    if ekey is not None:
-                        ok[uid] = True
-                        vals[uid] = interner.intern(ekey)
-                else:
-                    ok[uid] = True
-                    vals[uid] = bool(v) if isinstance(v, bool) else True
+            f32_unsafe = _table_fill(tr, new_ids, interner, ok,
+                                     vals) or f32_unsafe
         state["tables"][tr.name] = evaluated | set(new_ids)
 
     # ---- parametric tables: new distinct values get new dense slots
@@ -1463,17 +1557,13 @@ def update_bindings(spec: PrepSpec, table: ResourceTable,
             # value-slot axis and stay whole-upload (they are [C, u_pad])
             append_rows[pt.name + ".vmap"] = \
                 np.asarray(sorted(new_ids), dtype=np.int64)
-            distinct = pst["distinct"]
-            for gid in new_ids:
+            cols = _ptable_rows(pt, list(pst["distinct"]), new_ids,
+                                interner)
+            for j, gid in enumerate(new_ids):
                 u = len(u_of)
                 u_of[gid] = u
                 vmap_arr[gid] = u
-                key = interner.string(gid)
-                arg = decode_value(key) if pt.src_val else key
-                col = np.zeros((len(distinct),), dtype=bool)
-                for pstr, pi in distinct.items():
-                    v = _eval_host(pt.fn, arg, pstr)
-                    col[pi] = bool(v) if v is not None and v is not False else False
+                col = cols[:, j]
                 if tbl.shape[0]:
                     tbl[:, u] = col
                 for ci, lst in enumerate(pst["per_con"]):

@@ -13,7 +13,8 @@ import pytest
 from gatekeeper_tpu.api.templates import compile_target_rego
 from gatekeeper_tpu.engine.veval import ProgramExecutor
 from gatekeeper_tpu.ir.lower import CannotLower, lower_template
-from gatekeeper_tpu.ir.prep import build_bindings, update_bindings
+from gatekeeper_tpu.ir.prep import (TABLE_VALUES, build_bindings,
+                                    update_bindings)
 from gatekeeper_tpu.library.templates import LIBRARY, all_docs
 from gatekeeper_tpu.library.workload import make_mixed
 from gatekeeper_tpu.store.table import ResourceMeta, ResourceTable
@@ -100,6 +101,70 @@ class TestUpdateBindingsParity:
                     self._compare(lp.program, lp.spec, b, fresh, kind)
                 nxt.append((kind, lp, cons, b))
             cases = nxt
+
+    @pytest.mark.parametrize("kind", [
+        "K8sAllowedRepos", "K8sDisallowLatestTag", "K8sDisallowedRepos",
+        "K8sDisallowedTags"])
+    def test_new_image_churn_tables_equal_a_fresh_build(self, kind):
+        """A churn batch of images never seen before goes through
+        update_bindings' table and ptable sections (the batched builtin
+        path); every table then reads as a fresh build's does."""
+        rng = random.Random(23)
+        table = ResourceTable()
+
+        def pod(i, image):
+            return {"kind": "Pod", "apiVersion": "v1",
+                    "metadata": {"name": f"p{i}", "namespace": "ns"},
+                    "spec": {"containers": [{"name": "c", "image": image}]}}
+
+        # 100 distinct images: table buckets with room for the new ones
+        _fill(table, [pod(i, f"{('gcr.io', 'docker.io', 'quay.io')[i % 3]}"
+                             f"/app{i % 100}"
+                             f"{('', ':latest', ':v1', ':v2')[i % 4]}")
+                      for i in range(100)])
+        lp = dict(_lowered_library())[kind]
+        cons = _constraints_for(kind) + [
+            {"kind": kind, "metadata": {"name": "wide"},
+             "spec": {"parameters": {"repos": ["gcr.io/", "", "quay.io/"],
+                                     "tags": ["latest", "", "v2"]}}}]
+        prev = build_bindings(lp.spec, table, cons)
+        images = ["quay.io/new:latest", "quay.io/new", "gcr.io/x:v2",
+                  "docker.io/y:", "ünï:v2", "", "gcr.io/", 5, None]
+        for i, img in zip(rng.sample(range(100), len(images)), images):
+            _fill(table, [pod(i, img)], start=i)
+        before = TABLE_VALUES.batched
+        b = update_bindings(lp.spec, table, cons, prev)
+        assert b is not None
+        # the new images alone, on the batched path
+        assert 0 < TABLE_VALUES.batched - before < len(images) * 8
+        fresh = build_bindings(lp.spec, table, cons)
+
+        def live(src):
+            # ids the rows hold now: the delta keeps the evaluations of
+            # replaced values too, which no row reads
+            ids = np.unique(fresh.arrays[src])
+            return ids[ids >= 0]
+
+        for tr in lp.spec.tables:
+            ids = live(tr.src)
+            for suffix in (".ok", ".v"):
+                np.testing.assert_array_equal(
+                    b.arrays[tr.name + suffix][ids],
+                    fresh.arrays[tr.name + suffix][ids],
+                    err_msg=f"{kind} {tr.name}{suffix}")
+        # ptable value slots may be permuted: compare each value's column
+        for pt in lp.spec.ptables:
+            ids = live(pt.src)
+            for suffix in (".any", ".all"):
+                got = b.arrays[pt.name + suffix][
+                    :, b.arrays[pt.name + ".vmap"][ids]]
+                want = fresh.arrays[pt.name + suffix][
+                    :, fresh.arrays[pt.name + ".vmap"][ids]]
+                np.testing.assert_array_equal(
+                    got, want, err_msg=f"{kind} {pt.name}{suffix}")
+        ex = ProgramExecutor()
+        np.testing.assert_array_equal(ex.run(lp.program, b),
+                                      ex.run(lp.program, fresh))
 
     def test_recycle_ping_pong_parity(self):
         """update_bindings with `recycle` (the ping-pong write-buffer

@@ -252,3 +252,22 @@ def test_sweep_span_counts_device_and_scalar_templates(tracer, client):
              if e["name"] == "kind.format"}
     assert modes["K8sAgileLabels"] == "scalar"
     assert {modes[k] for k in KINDS} == {"topk"}
+
+
+def test_host_prep_spans_count_table_values_by_path(tracer, client):
+    client.audit(limit_per_constraint=20, full=True)
+    client.audit(limit_per_constraint=20, full=True)
+    evs = tracer.export()["traceEvents"]
+    preps = [e["args"] for e in evs if e["name"] == "kind.host_prep"]
+    assert all("table_values_batched" in a and "table_values_host" in a
+               for a in preps)
+    by_kind = collections.defaultdict(list)
+    for a in preps:
+        by_kind[a["kind"]].append((a["table_values_batched"],
+                                   a["table_values_host"]))
+    # each full sweep rebuilds the image tables, all on the batched path
+    # (startswith / endswith / contains of the image); labels have none
+    for kind in ("K8sAllowedRepos", "K8sDisallowedTags"):
+        assert len(by_kind[kind]) == 2
+        assert all(b > 0 and h == 0 for b, h in by_kind[kind]), kind
+    assert by_kind["K8sRequiredLabels"] == [(0, 0), (0, 0)]
