@@ -26,7 +26,6 @@ RUN pip install --no-cache-dir numpy pyyaml
 
 WORKDIR /app
 COPY gatekeeper_tpu /app/gatekeeper_tpu
-COPY bench.py /app/bench.py
 
 # warm the native extension build at image build time
 RUN python -c "from gatekeeper_tpu import native; print('native:', native.available)"

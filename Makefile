@@ -1,12 +1,12 @@
-# gatekeeper-tpu build/test/bench targets.
-# Reference analogue: /root/reference/Makefile:34-48 (native-test / test /
-# manager / deploy); the engine here is jax so "manager" is a python entry
-# point and "bench" replaces the reference's (absent) perf harness.
+# gatekeeper-tpu build/test targets.
+# Reference analogue: the reference Makefile's native-test / test /
+# manager / deploy; the engine here is jax so "manager" is a python entry
+# point.  The benchmark is benchmark/run.py (see BENCHMARK.json).
 
 IMG ?= gatekeeper-tpu:latest
 PY ?= python
 
-.PHONY: all native-test test soak bench bench-quick probe demo demo-basic demo-agilebank manager worker \
+.PHONY: all native-test test soak probe demo demo-basic demo-agilebank manager worker \
         docker-build deploy undeploy lint ci
 
 all: test
@@ -23,13 +23,6 @@ test: native-test
 # before cutting a release image and nightly in CI — see ci.sh.
 soak:
 	GATEKEEPER_SOAK=1 $(PY) -m pytest tests/test_soak.py -q
-
-# the ONE-json-line benchmark contract (driver runs this on real TPU)
-bench:
-	$(PY) bench.py
-
-bench-quick:
-	GATEKEEPER_BENCH_QUICK=1 $(PY) bench.py
 
 # self-validate both engines via the framework's Probe
 # (client/probe.py — the reference's probe_client readiness surface)

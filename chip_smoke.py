@@ -256,7 +256,7 @@ def compare(where: str, got: list[tuple], want: list[tuple]) -> dict:
 
 def north_star_inventory(n: int, per_kind: int, seed: int):
     """(templates, constraints, resources): the BASELINE.json north star
-    built as bench.py builds it."""
+    from library/workload.py's generators."""
     from gatekeeper_tpu.library.workload import make_pods, north_star_docs
     resources = make_pods(n, random.Random(seed))
     templates, constraints = north_star_docs(random.Random(seed + 1),

@@ -96,26 +96,20 @@ class _RowOrder:
         return int(p)
 
 
-ORDER_NUMPY_MIN_ROWS = 65_536
-"""Above this many keys the audit row order is sorted with numpy and
-its sorted keys are kept, so that later key changes merge into them; at
-or below it a Python sort and a row -> position dict."""
-
-
 @dataclasses.dataclass
 class _OrderCache:
     """The audit row order of one table at one (remap_generation,
-    key_generation).  On the numpy path ``keys`` holds the sorted keys
-    (a list of the table's own strings) and ``rows`` their rows, for the
-    next merge; ``merged`` counts the changed keys merged in to build
-    it (0 for a full sort)."""
+    key_generation).  ``keys`` holds the sorted keys (a list of the
+    table's own strings) and ``rows`` their rows, for the next merge;
+    ``merged`` counts the changed keys merged in to build it (0 for a
+    full sort)."""
     table: Any
     remap: int
     kgen: int
     ordered_rows: list
-    row_order: Any
-    keys: list | None = None
-    rows: np.ndarray | None = None
+    row_order: _RowOrder
+    keys: list
+    rows: np.ndarray
     merged: int = 0
 
     def holds(self, table) -> bool:
@@ -184,24 +178,16 @@ REVIEW_BATCH_MIN_EVALS = _env_int(
 """Below this many (review, constraint) pairs, a coalesced admission
 batch stays on the scalar engine.
 
-The value was set before PR 1 and has not been measured on a local
-chip: with the webhook's default --max-batch 64 (and 200 constraints = 12.8k
-evals) admission never routes to the device.  bench.py's
-detail.admission_device_batch table is the measurement to derive it
-from (ROADMAP Queue 1 item 5); GATEKEEPER_REVIEW_BATCH_MIN_EVALS moves
+The value has not been measured on a chip: with the webhook's default
+--max-batch 64 (and 200 constraints = 12.8k evals) admission never
+routes to the device.  The planned benchmark cell of device review
+batches at the large-cluster inventory (ROADMAP Queue 2 item 9) is the
+measurement to derive it from; GATEKEEPER_REVIEW_BATCH_MIN_EVALS moves
 it until then.  See README "Device-batched admission"."""
 
 DEFAULT_PREWARM_CAP = 20
 """Cap assumed for prewarmed audit executables — the audit manager's
 per-constraint violation cap (reference pkg/audit/manager.go:35)."""
-
-FULL_SWEEP_SERIAL = os.environ.get("GATEKEEPER_FULL_SWEEP_SERIAL") == "1"
-"""Diagnostic baseline: run a forced-full sweep (QueryOpts.full) with
-NO pipelining — each kind's host prep, H2D upload, and device execution
-complete before the next kind's prep starts.  bench.py measures this
-no-overlap serial number against the pipelined full sweep; it is the
-measurement the pipeline exists to beat.  Never enable in production."""
-
 
 class _ResolvedHandle:
     """Pre-resolved stand-in for a PendingTopK/PendingMask handle:
@@ -966,7 +952,7 @@ class JaxDriver(LocalDriver):
         chain (O(dirty) host work + row-sized device scatters).  ``off``
         rebuilds bindings whole on every store generation — the
         bit-identical oracle for the delta chain, and the re-stage
-        comparator the devpages_churn bench measures H2D against."""
+        comparator tests/test_devpages.py measures H2D against."""
         import os
         return os.environ.get(
             "GATEKEEPER_BINDING_DELTA", "on").lower() not in ("off", "0")
@@ -1863,26 +1849,19 @@ class JaxDriver(LocalDriver):
     def _ensure_order(self, st):
         """Sorted-cache-key row order (matches the scalar driver), cached
         per table, remap_generation and key_generation; pure updates
-        never re-order.  Above ORDER_NUMPY_MIN_ROWS keys, a key change
-        the table's key log still covers (``keys_changed_since``) is
-        merged into the cached sorted keys; otherwise, after a
-        wipe or compact, past the log's bound, on another table or at
-        or below that size, the keys are sorted from scratch.  Both
-        give the same order."""
+        never re-order.  A key change the table's key log still covers
+        (``keys_changed_since``) is merged into the cached sorted keys;
+        otherwise, after a wipe or compact, past the log's bound or on
+        another table, the keys are sorted from scratch.  Both give the
+        same order."""
         table = st.table
         cache = st.order_cache
         if cache is not None and cache.holds(table):
             return cache.ordered_rows, cache.row_order
         remap, kgen = table.remap_generation, table.key_generation
-        if len(table) <= ORDER_NUMPY_MIN_ROWS:
-            ordered_rows = [row for _, row in sorted(table.rows_items())]
-            built = _OrderCache(table, remap, kgen, ordered_rows,
-                                {row: i for i, row in enumerate(ordered_rows)})
-            st.order_cache = built
-            return built.ordered_rows, built.row_order
         changed = None
-        if cache is not None and cache.keys is not None \
-                and cache.table is table and cache.remap == remap:
+        if cache is not None and cache.table is table \
+                and cache.remap == remap:
             changed = table.keys_changed_since(cache.kgen)
         if changed is not None:
             keys, ordered_rows, rows = _merge_sorted_keys(
@@ -2664,7 +2643,6 @@ class JaxDriver(LocalDriver):
             # ground truth for the attribution drift report
             per_kind_dev: dict[str, float] = {}
             ph_lock = _threading.Lock()
-            serial_full = full and FULL_SWEEP_SERIAL
 
             def _launch(mode, prog, bindings):
                 if mode == "topk":
@@ -3001,12 +2979,7 @@ class JaxDriver(LocalDriver):
                                     futures.append(tf)
                                     specs.append(spec)
                                     continue
-                            # serial_full: the no-overlap diagnostic
-                            # baseline — dispatch inline and (because
-                            # dispatch blocks on full sweeps) finish
-                            # this kind end-to-end before the next
-                            # kind's prep
-                            if ordered_dispatch or serial_full:
+                            if ordered_dispatch:
                                 f = concurrent.futures.Future()
                                 try:
                                     f.set_result(dispatch(spec))
@@ -3207,7 +3180,7 @@ class JaxDriver(LocalDriver):
                 overlap = max(0.0, (sum_ph - pipeline_wall) / sum_ph) \
                     if sum_ph > 0 else 0.0
                 self.last_sweep_phases = {
-                    "full": True, "serial": serial_full,
+                    "full": True,
                     "host_prep_s": round(ph["host_prep_s"], 6),
                     "h2d_s": round(ph["h2d_s"], 6),
                     "device_s": round(ph["device_s"], 6),
@@ -3666,7 +3639,8 @@ class JaxDriver(LocalDriver):
                                     (c.get("metadata") or {}).get("name", "")), r))
                 emitted += len(results)
 
-    def _row_rank(self, st: JaxTargetState, row_order: dict) -> np.ndarray:
+    def _row_rank(self, st: JaxTargetState,
+                  row_order: _RowOrder) -> np.ndarray:
         """[n_rows] int32: row -> sorted-cache-key rank.  The device
         top-k scores by this rank so the capped subset matches the
         scalar driver's cap order (not raw table row order, which
@@ -3677,16 +3651,10 @@ class JaxDriver(LocalDriver):
             return st.rank_cache[1]
         n = st.table.n_rows
         rank = np.full((n,), n - 1, dtype=np.int32)
-        if isinstance(row_order, _RowOrder):
-            m = min(len(row_order._pos), n)
-            pos = row_order._pos[:m]
-            valid = pos >= 0
-            rank[:m][valid] = pos[valid].astype(np.int32)
-        elif row_order:
-            rows = np.fromiter(row_order.keys(), dtype=np.int64,
-                               count=len(row_order))
-            rank[rows] = np.fromiter(row_order.values(), dtype=np.int32,
-                                     count=len(row_order))
+        m = min(len(row_order._pos), n)
+        pos = row_order._pos[:m]
+        valid = pos >= 0
+        rank[:m][valid] = pos[valid].astype(np.int32)
         st.rank_cache = (kgen, rank)
         return rank
 

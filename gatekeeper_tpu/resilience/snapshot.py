@@ -23,7 +23,7 @@ tiers, on every backend, alongside the XLA cache:
   table as plain data (``ResourceTable.snapshot_state()``).
 
 Activation is explicit: snapshots read/write only when
-``GATEKEEPER_SNAPSHOT_DIR`` is set (bench, ci restart-smoke, and the
+``GATEKEEPER_SNAPSHOT_DIR`` is set (the ci restart smoke and the
 manager set it; unit tests stay hermetic by default).
 
 **Why a custom pickler.**  A ``LoweredProgram``'s PrepSpec carries

@@ -336,8 +336,8 @@ def build_row_records(arrays: dict[str, Any], rows: np.ndarray,
     skipped; a change to one invalidates the whole binding set
     upstream, so records would be meaningless).  Returns the records
     plus the total staged byte count — the number the
-    ``store_h2d_bytes_total`` metric and the devpages_churn bench row
-    account against whole-page re-upload."""
+    ``store_h2d_bytes_total`` metric accounts against whole-page
+    re-upload."""
     records: list[RowRecord] = []
     total = 0
     for name, arr in arrays.items():

@@ -1,7 +1,7 @@
 """Test configuration.
 
 Tests run JAX on a virtual 8-device CPU mesh; the chip runs through
-chip_smoke.py and bench.py, which leave the platform alone.
+chip_smoke.py and benchmark/run.py, which leave the platform alone.
 jax.config.update after import is authoritative over JAX_PLATFORMS, so
 the pin holds whatever the environment names.
 """

@@ -269,8 +269,7 @@ def test_capped_subset_matches_scalar_after_churn():
     from gatekeeper_tpu.parallel.sharding import make_mesh, run_sharded_audit
     constraints = jx.driver._kind_constraints(st, "CompoundEq")
     bindings = build_bindings(compiled.vectorized.spec, st.table, constraints)
-    ordered_rows = [row for _, row in sorted(st.table.rows_items())]
-    row_order = {row: i for i, row in enumerate(ordered_rows)}
+    _ordered_rows, row_order = jx.driver._ensure_order(st)
     rank = jx.driver._row_rank(st, row_order)
     mesh = make_mesh(8)
     counts, rows, valid = run_sharded_audit(

@@ -1,9 +1,10 @@
 """Cold-start path semantics: bulk ingest, numpy row ordering, and
 narrow device transfers must be behavior-preserving optimizations.
 
-The perf claims live in bench.py; these tests pin the *equivalences*
-the optimizations rely on (batch AddData == looped AddData, _RowOrder
-== dict ordering, int8/int16 narrow transfer == int32 upload)."""
+The benchmark (benchmark/) measures their speed; these tests pin the
+*equivalences* the optimizations rely on (batch AddData == looped
+AddData, _RowOrder == dict ordering, int8/int16 narrow transfer ==
+int32 upload)."""
 
 import numpy as np
 import pytest

@@ -129,6 +129,15 @@ class TestAnalyzer:
         names = {n for n, _cls in cert.bindings}
         assert {"__alive__", "__match__", "__rank__"} <= names
 
+    def test_library_keeps_forty_five_templates_certified(self):
+        certs = []
+        for tdoc, _cdoc in all_docs():
+            kind = tdoc["spec"]["crd"]["spec"]["names"]["kind"]
+            _c, lowered, _ = _library(kind)
+            certs.append(compilesurface.analyze(kind, lowered))
+        assert sum(c.bounded and not c.scalar_pin for c in certs) >= 45
+        assert not [c for c in certs if not c.bounded]
+
     def test_caps_bound_the_signature_count(self, monkeypatch):
         _c, lowered, _ = _library("K8sRequiredLabels")
         full = compilesurface.analyze("K8sRequiredLabels", lowered)

@@ -1,7 +1,7 @@
 """Forced-full-sweep audit (QueryOpts.full / Client.audit(full=True)):
 parity with the scalar oracle and the memoized path, cache
-invalidation, pipeline phase metering, and the serial no-overlap
-diagnostic baseline.
+invalidation, pipeline phase metering, and the inline dispatch that a
+mesh spanning processes takes.
 
 VERDICT §weak #4 context: the steady-state audit number is delta/memo
 replay.  audit(full=True) drops the mask/bindings/format memoization
@@ -117,7 +117,7 @@ def test_full_sweep_device_path_parity_and_phases(monkeypatch):
     assert jd.state[TARGET_NAME].bindings_cache
 
     phases = jd.last_sweep_phases
-    assert phases["full"] is True and phases["serial"] is False
+    assert phases["full"] is True
     for k in ("host_prep_s", "h2d_s", "device_s", "pipeline_wall_s",
               "overlap_fraction", "h2d_bytes"):
         assert k in phases
@@ -151,20 +151,31 @@ def test_full_sweep_device_path_parity_and_phases(monkeypatch):
 
 
 def test_full_sweep_serial_mode_matches_pipelined(monkeypatch):
-    """FULL_SWEEP_SERIAL (the bench's no-overlap baseline) changes only
-    scheduling, never results — and is flagged in the phase record."""
+    """The inline dispatch a mesh spanning processes takes (each kind
+    dispatched from the sweep thread, in kind order) changes only
+    scheduling, never results: the forced full sweep equals the pooled
+    one."""
+    from gatekeeper_tpu.engine import veval
+
     monkeypatch.setattr(jd_mod, "SMALL_WORKLOAD_EVALS", 0)
     rng = random.Random(9)
     jd, c, _resources, _params = _small_device_client(rng)
 
-    piped = _keys(c.audit(full=True).results())
-    assert jd.last_sweep_phases["serial"] is False
+    pooled = _keys(c.audit(full=True).results())
+    assert pooled
 
-    monkeypatch.setattr(jd_mod, "FULL_SWEEP_SERIAL", True)
-    serial = _keys(c.audit(full=True).results())
-    assert serial == piped
+    spans = []
+
+    def spans_processes(mesh):
+        spans.append(mesh)
+        return True
+
+    monkeypatch.setattr(veval, "mesh_spans_processes", spans_processes)
+    inline = _keys(c.audit(full=True).results())
+    assert spans, "the sweep never asked whether its mesh spans processes"
+    assert inline == pooled
     phases = jd.last_sweep_phases
-    assert phases["full"] is True and phases["serial"] is True
+    assert phases["full"] is True
     assert phases["device_s"] > 0
 
 

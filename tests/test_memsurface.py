@@ -158,6 +158,28 @@ class TestOverApprox:
         assert under == []
         assert cert.has_r      # devpages terms apply to row-axis kinds
 
+    def test_swept_claims_are_within_three_times_the_built_bytes(
+            self, monkeypatch):
+        """Summed over a swept driver's kinds, the resident claims at
+        the geometry the sweep built cover the bytes of the built
+        arrays and stay within three times them."""
+        monkeypatch.setattr(jd_mod, "SMALL_WORKLOAD_EVALS", 0)
+        jd, _client = _driver(KINDS, n_rows=200)
+        jd.query_audit(TARGET_NAME, QueryOpts(limit_per_constraint=20,
+                                              full=True))
+        st = jd.state[TARGET_NAME]
+        t_pad = prep.interner_bucket(len(st.table.interner))
+        claimed = built = 0
+        for kind in KINDS:
+            b = st.bindings_cache[kind][1]
+            dims = {"c": b.c_pad, "r": b.r_pad, "t": t_pad}
+            if b.e_pads:
+                dims["e"] = max(b.e_pads.values())
+            claimed += st.memsurfaces[kind].resident_bytes(
+                dims, shapes={k: a.shape for k, a in b.arrays.items()})
+            built += sum(int(a.nbytes) for a in b.arrays.values())
+        assert 0 < built <= claimed <= 3 * built
+
     def test_seeded_underclaim_fails_the_check(self, monkeypatch):
         monkeypatch.setenv("GATEKEEPER_MEMSURFACE_TEST_UNDER",
                            "K8sContainerLimits")

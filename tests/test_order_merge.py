@@ -1,8 +1,8 @@
 """The audit row order across key changes.
 
-Above ``ORDER_NUMPY_MIN_ROWS`` keys the driver keeps the sorted key
-array and merges the keys the table logged as inserted or removed into
-it, instead of sorting every key again.  After any sequence of creates,
+The driver keeps the sorted key array and merges the keys the table
+logged as inserted or removed into it, instead of sorting every key
+again.  After any sequence of creates,
 deletes, re-creates, pure updates and reused free rows the merged order,
 its row -> position map and the rank array equal a fresh sort's; a wipe,
 a compact, a log past its bound and another table object each fall back
@@ -35,12 +35,6 @@ def _put(table: ResourceTable, key: str, tag: int = 0) -> int:
     name = key.rsplit("/", 1)[1]
     return table.upsert(key, {"metadata": {"name": name}, "tag": tag},
                         ResourceMeta("v1", "Pod", name, "ns"))
-
-
-@pytest.fixture
-def numpy_order(monkeypatch):
-    """The numpy (mergeable) path from a handful of keys on."""
-    monkeypatch.setattr(jd_mod, "ORDER_NUMPY_MIN_ROWS", 8)
 
 
 @pytest.fixture
@@ -113,7 +107,7 @@ def test_key_log_drops_its_older_half_past_the_bound(monkeypatch):
 
 @pytest.mark.parametrize("seed", [1, 2, 3, 4])
 def test_merged_order_equals_a_fresh_sort_after_every_step(
-        numpy_order, driver, seed):
+        driver, seed):
     rng = random.Random(seed)
     t, keys = _table()
     live = set(keys)
@@ -159,8 +153,7 @@ def test_merged_order_equals_a_fresh_sort_after_every_step(
     assert merged_steps > 0
 
 
-def test_a_key_created_and_deleted_between_orders_is_ignored(
-        numpy_order, driver):
+def test_a_key_created_and_deleted_between_orders_is_ignored(driver):
     t, keys = _table(50)
     st = jd_mod.JaxTargetState()
     st.table = t
@@ -171,7 +164,7 @@ def test_a_key_created_and_deleted_between_orders_is_ignored(
     assert _assert_fresh(driver, st) == 2
 
 
-def test_a_cache_hit_keeps_the_order_object(numpy_order, driver):
+def test_a_cache_hit_keeps_the_order_object(driver):
     t, _keys = _table(50)
     st = jd_mod.JaxTargetState()
     st.table = t
@@ -183,8 +176,7 @@ def test_a_cache_hit_keeps_the_order_object(numpy_order, driver):
 
 @pytest.mark.parametrize("fallback", ["wipe", "compact", "overflow",
                                       "table"])
-def test_each_fallback_sorts_from_scratch(numpy_order, driver, monkeypatch,
-                                          fallback):
+def test_each_fallback_sorts_from_scratch(driver, monkeypatch, fallback):
     monkeypatch.setattr(table_mod, "KEY_LOG_MIN", 16)
     t, keys = _table(60)
     st = jd_mod.JaxTargetState()
@@ -207,15 +199,17 @@ def test_each_fallback_sorts_from_scratch(numpy_order, driver, monkeypatch,
 
 
 def test_small_tables_keep_the_dict_order(driver):
-    t, keys = _table(50)
+    """A table of a few keys takes the same mergeable order as a large
+    one: a ``_RowOrder`` equal to a fresh sort, before and after a
+    delete merged into it."""
+    t, keys = _table(5)
     st = jd_mod.JaxTargetState()
     st.table = t
-    ordered, row_order = driver._ensure_order(st)
-    assert isinstance(row_order, dict) and st.order_cache.keys is None
+    assert _assert_fresh(driver, st) == 0
     t.remove(keys[0])
-    ordered, row_order = driver._ensure_order(st)
+    assert _assert_fresh(driver, st) == 1
+    ordered, _row_order = driver._ensure_order(st)
     assert ordered == [r for _, r in sorted(t.rows_items())]
-    assert st.order_cache.merged == 0
 
 
 # -- audit level ------------------------------------------------------
@@ -250,7 +244,6 @@ def _by_constraint(results: list) -> dict:
 
 
 def test_memoized_capped_audit_under_churn_equals_the_oracle(monkeypatch):
-    monkeypatch.setattr(jd_mod, "ORDER_NUMPY_MIN_ROWS", 1000)
     monkeypatch.setattr(jd_mod, "SMALL_WORKLOAD_EVALS", 0)
     tracer = trace_mod.Tracer(ring=4096)
     monkeypatch.setattr(trace_mod, "_tracer", tracer)

@@ -264,6 +264,41 @@ class TestPagedSweep:
         assert pg.get("kinds_paged") == len(self.KINDS)
         assert pg.get("kinds_fallback") == 0
 
+    def test_one_row_churn_reevaluates_under_five_percent(self,
+                                                           monkeypatch):
+        """One verdict-flipping image edit: the paged sweep equals the
+        kind-granular one (pages off) and the full oracle (pages and
+        footprints off), and re-evaluates under 5 % of the
+        row-evaluation space."""
+        import gatekeeper_tpu.engine.jax_driver as jd_mod
+        monkeypatch.setattr(jd_mod, "SMALL_WORKLOAD_EVALS", 0)
+        monkeypatch.setenv("GATEKEEPER_PAGE_ROWS", "16")
+        n = 400
+        resources = make_mixed(random.Random(13), n)
+        edit = copy.deepcopy(next(
+            o for o in resources[n // 2:]
+            if (o.get("spec") or {}).get("containers")))
+        for cont in edit["spec"]["containers"]:
+            cont["image"] = "evil.io/pages:1"
+        opts = QueryOpts(limit_per_constraint=20)
+        got = {}
+        for leg, pages, fp in (("oracle", "off", "off"),
+                               ("kind", "off", "on"),
+                               ("paged", "on", "on")):
+            monkeypatch.setenv("GATEKEEPER_PAGES", pages)
+            monkeypatch.setenv("GATEKEEPER_FOOTPRINT", fp)
+            jd, c = _mk_client(jd_mod, self.KINDS)
+            c.add_data_batch(copy.deepcopy(resources))
+            jd.query_audit(TARGET_NAME, opts)
+            c.add_data(copy.deepcopy(edit))
+            got[leg] = _verdicts(jd.query_audit(TARGET_NAME, opts)[0])
+        assert got["paged"] == got["kind"] == got["oracle"]
+        pg = dict(jd.last_sweep_phases.get("pages") or {})
+        assert pg["kinds_paged"] == len(self.KINDS)
+        rows_frac = pg["rows_reevaluated"] / (n * pg["kinds_paged"])
+        assert 0 < rows_frac < 0.05
+        assert pg["evaluations_saved"] > 0
+
     def test_metadata_noise_skips_pages(self, monkeypatch):
         resources, jd_p, cp, _jd_o, _co = self._drivers(monkeypatch)
         opts = QueryOpts(limit_per_constraint=20)

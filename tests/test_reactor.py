@@ -137,6 +137,32 @@ def test_coalescing_many_events_one_react():
     assert fx.live_verdicts() == fx.oracle_verdicts()
 
 
+def test_event_stream_pumped_one_by_one_matches_the_oracle():
+    """Events pumped one at a time, each an image edit that flips a
+    K8sAllowedRepos verdict or a label edit, leave the live verdicts
+    equal to a pages-off sweep of the final cluster."""
+    fx = _Fixture(n=40, seed=29)
+    pods = [o for o in fx.resources
+            if (o.get("spec") or {}).get("containers")]
+    assert pods
+    n_events = 12
+    for j in range(n_events):
+        src = fx.rng.choice(pods if j % 2 else fx.resources)
+        cur = fx.cluster.get(gvk_of(src), src["metadata"]["name"],
+                             src["metadata"].get("namespace"))
+        o = copy.deepcopy(cur)
+        if j % 2:
+            for cont in o["spec"]["containers"]:
+                cont["image"] = f"evil.io/watch:{j}"
+        else:
+            o.setdefault("metadata", {}).setdefault(
+                "labels", {})["watch"] = f"r{j}"
+        fx.cluster.update(o)
+        fx.rx.pump()
+    assert fx.rx.counters["events"] == n_events
+    assert fx.live_verdicts() == fx.oracle_verdicts()
+
+
 def test_gap_confirmed_escalates_to_kind_resync(monkeypatch):
     monkeypatch.setenv("GATEKEEPER_REACTOR_GAP_GRACE_S", "0.05")
     fx = _Fixture()

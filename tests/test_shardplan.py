@@ -112,6 +112,14 @@ class TestAnalyzer:
         plan = shardplan.analyze("K8sUniqueIngressHost", lowered)
         assert plan.reason == want
 
+    def test_library_keeps_forty_templates_shard_eligible(self):
+        eligible = 0
+        for tdoc, _cdoc in all_docs():
+            kind = tdoc["spec"]["crd"]["spec"]["names"]["kind"]
+            _c, lowered, _ = _library(kind)
+            eligible += shardplan.analyze(kind, lowered).eligible
+        assert eligible >= 40
+
     def test_digest_pins_program_and_spec(self):
         _c, lowered, _ = _library("K8sRequiredLabels")
         _c2, lowered2, _ = _library("K8sAllowedRepos")
@@ -348,6 +356,14 @@ class TestShardedSweep:
         assert st_sharded.get("kinds_sharded", 0) >= 1
         assert st_sharded.get("kinds_replicated", 0) >= 1
         assert st_sharded.get("per_shard_evals", 0) > 0
+        assert st_sharded.get("collectives", 0) > 0
+
+    def test_four_shard_parity_with_unsharded_oracle(self, monkeypatch):
+        v_oracle, _st_oracle = self._run(1, monkeypatch)
+        v_sharded, st_sharded = self._run(4, monkeypatch)
+        assert v_sharded == v_oracle
+        assert st_sharded.get("shards") == 4
+        assert st_sharded.get("kinds_sharded", 0) >= 1
         assert st_sharded.get("collectives", 0) > 0
 
     def test_off_mode_gating_disabled(self, monkeypatch):

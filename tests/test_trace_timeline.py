@@ -141,8 +141,8 @@ def test_sweep_spans_are_direct_children_with_their_counts(tracer,
     assert args("store.put_batch")[-1]["rows"] == 5
     assert [a["rows"] for a in args("audit.order")] == [300, 305]
     assert args("audit.order")[1]["resorted"] is True    # creates
-    # 305 keys sort as a dict: nothing to merge into
-    assert [a["merged"] for a in args("audit.order")] == [0, 0]
+    # the five created keys merge into the first sort's order
+    assert [a["merged"] for a in args("audit.order")] == [0, 5]
     assert all(a["h2d_bytes"] > 0 for a in args("kind.h2d"))
     assert sum(a["h2d_bytes"] for a in args("device.dispatch")) > 0
     assert sum(a["rows"] for a in args("kind.format")) > 0
@@ -158,9 +158,7 @@ def test_memoized_sweep_reuses_the_order_of_unchanged_keys(tracer, client):
     assert [a["merged"] for a in order] == [0, 0]
 
 
-def test_a_churned_sweep_merges_its_changed_keys(monkeypatch, tracer,
-                                                 client):
-    monkeypatch.setattr(jd_mod, "ORDER_NUMPY_MIN_ROWS", 100)
+def test_a_churned_sweep_merges_its_changed_keys(tracer, client):
     client.audit(limit_per_constraint=20)
     client.add_data_batch(make_mixed(random.Random(9), 5))
     client.audit(limit_per_constraint=20)

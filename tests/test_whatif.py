@@ -271,6 +271,20 @@ class TestFleet:
             assert rep.kinds_stacked, rep.kinds_replicated
             assert rep.device_dispatches == len(rep.kinds_stacked)
 
+    def test_four_cluster_stack_matches_loop_oracle(self):
+        """Four equal-sized clusters stack into one sweep whose verdicts
+        equal the per-cluster loop's."""
+        templates, constraints = _policy_subset()
+        clusters = [
+            make_cluster(f"c{i}", templates, constraints,
+                         objs=make_mixed(random.Random(100 + i), 50))
+            for i in range(4)]
+        rep = fleet_audit(clusters, 20)
+        verdicts, digests, _wall = fleet_loop_oracle(clusters, 20)
+        assert rep.n_clusters == 4
+        assert rep.digests == digests
+        assert rep.verdicts == verdicts
+
     def test_cluster_from_store_state(self, live):
         """A cluster seeded from a store snapshot audits identically to
         the client the snapshot came from."""
