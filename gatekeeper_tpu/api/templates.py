@@ -82,6 +82,12 @@ class CompiledTemplate:
             self._interp = Interpreter(self.module)
         return self._interp
 
+    def fn_memo_counts(self) -> tuple[int, int]:
+        """(hits, misses) of the interpreter's pure-function memo so
+        far, without building an interpreter that is not there yet."""
+        it = self._interp
+        return (0, 0) if it is None else (it.fn_memo_hits, it.fn_memo_misses)
+
     def violations(self, input_doc, data_doc, tracer=None) -> list:
         return self.interp.query_set("violation", input_doc, data_doc, tracer=tracer)
 

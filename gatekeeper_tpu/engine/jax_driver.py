@@ -3028,16 +3028,22 @@ class JaxDriver(LocalDriver):
                     # kind.format: the fetch, then the capped rows
                     # formatted; ``rows`` counts the (constraint, row)
                     # pairs the scalar oracle re-evaluated (format-memo
-                    # misses; the sweep thread is the one formatting)
+                    # misses; the sweep thread is the one formatting),
+                    # ``fn_memo_hits``/``fn_memo_misses`` the pure
+                    # function calls the per-review memo served / ran
                     nonlocal fmt_s
                     n0 = misses.value
+                    h0, f0 = spec[2].fn_memo_counts()
                     _tf = _time.perf_counter()
                     with _tracer.span("kind.format", cat="format",
                                       parent=_sweep_ctx, kind=spec[1],
                                       mode=spec[0]) as fsp:
                         _format_payload(spec, handle)
                         if fsp is not None:
+                            h1, f1 = spec[2].fn_memo_counts()
                             fsp.args["rows"] = misses.value - n0
+                            fsp.args["fn_memo_hits"] = h1 - h0
+                            fsp.args["fn_memo_misses"] = f1 - f0
                     fmt_s += _time.perf_counter() - _tf
 
                 def _format_payload(spec, handle):

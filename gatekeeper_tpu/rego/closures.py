@@ -29,7 +29,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, Iterator
 
-from gatekeeper_tpu.analysis.purity import is_impure_call
+from gatekeeper_tpu.analysis.purity import is_impure_builtin, is_impure_call
 from gatekeeper_tpu.errors import EvalError
 from gatekeeper_tpu.rego import builtins as bi
 from gatekeeper_tpu.rego.ast_nodes import (
@@ -607,9 +607,31 @@ class ClosureCompiler:
                     if v is not UNDEFINED:
                         yield v, env2
             return calln
-        # special forms and user functions keep the interpreter's exact
-        # handling (trace/internal.compare/time.now_ns/walk/user fns and
-        # the unknown-function error)
+        if len(name) == 1 and name[0] in interp.rules \
+                and name not in (("trace",), ("walk",)):
+            # user function: compiled arguments straight into the
+            # interpreter's call (and its per-review memo of pure calls)
+            call, fname = interp._call_function, name[0]
+            if len(args) == 1:
+                a0f = args[0]
+
+                def userfn1(ctx, env):
+                    for a0, env2 in a0f(ctx, env):
+                        v = call(ctx, fname, (a0,))
+                        if v is not UNDEFINED:
+                            yield v, env2
+                return userfn1
+            argseq = self._compile_seq(term.args, tuple)
+
+            def userfn(ctx, env):
+                for argvals, env2 in argseq(ctx, env):
+                    v = call(ctx, fname, argvals)
+                    if v is not UNDEFINED:
+                        yield v, env2
+            return userfn
+        # special forms keep the interpreter's exact handling
+        # (trace/internal.compare/time.now_ns/walk and the
+        # unknown-function error)
         def special(ctx, env):
             yield from interp._eval_call(ctx, term, env)
         return special
@@ -627,107 +649,9 @@ class ClosureCompiler:
         env — vars bound only during body evaluation read as a
         consistent miss sentinel there, and enclosing bindings (which
         may carry constraint-derived values) key the entry correctly."""
-        interp = self.interp
-        impure = False
-        names: set[str] = set()
-
-        def visit(t):
-            nonlocal impure
-            if impure or t is None:
-                return
-            cls = t.__class__
-            if cls is Var:
-                if t.name == "input":
-                    # a BARE input var binds the whole document —
-                    # including .constraint (the Ref branch below
-                    # handles the safe input.review.* base inline, so
-                    # this branch only sees whole-input references)
-                    impure = True
-                    return
-                if t.is_wildcard:
-                    return
-                if t.name in interp.rules:
-                    impure = True       # rule value: may read constraint
-                else:
-                    names.add(t.name)
-                return
-            if cls is Ref:
-                base = t.base
-                if base.__class__ is Var:
-                    if base.name == "data":
-                        impure = True   # inventory / external docs
-                        return
-                    if base.name == "input":
-                        p0 = t.path[0] if t.path else None
-                        if not (p0 is not None and p0.__class__ is Scalar
-                                and p0.value == "review"):
-                            impure = True   # input.constraint / dynamic
-                            return
-                        for p in t.path:
-                            visit(p)
-                        return
-                visit(base)
-                for p in t.path:
-                    visit(p)
-                return
-            if cls is Call:
-                nm = t.name
-                if is_impure_call(nm, interp.rules):
-                    impure = True       # impure builtin (clock/trace/jwt
-                    return              # verify) or user function (may
-                    #                     read constraint) — one gate
-                    #                     shared with the template vetter
-                    #                     (analysis/purity.py)
-                for a in t.args:
-                    visit(a)
-                return
-            if cls is Scalar:
-                return
-            if cls is Comprehension:
-                for h in t.head:
-                    visit(h)
-                for lit in t.body:
-                    _visit_lit(lit)
-                return
-            if cls in (ArrayTerm, SetTerm):
-                for x in t.items:
-                    visit(x)
-                return
-            if cls is ObjectTerm:
-                for k, v in t.pairs:
-                    visit(k)
-                    visit(v)
-                return
-            if cls is BinOp:
-                visit(t.lhs)
-                visit(t.rhs)
-                return
-            if cls is UnaryMinus:
-                visit(t.operand)
-                return
-            impure = True               # unknown node kind: stay safe
-
-        def _visit_lit(lit):
-            nonlocal impure
-            if impure:
-                return
-            if lit.withs:
-                impure = True           # document override inside
-                return
-            e = lit.expr
-            if e.__class__ in (Compare, Assign):
-                visit(e.lhs)
-                visit(e.rhs)
-            elif e.__class__ is SomeDecl:
-                names.update(e.names)
-            else:
-                visit(e)
-
-        for h in term.head:
-            visit(h)
-        for lit in term.body:
-            _visit_lit(lit)
-        return None if impure else tuple(sorted(names))
+        names = _reads(self.interp, term.head, term.body, review_ok=True,
+                       fn_ok=lambda nm: False)
+        return None if names is None else tuple(sorted(names))
 
     def _memoize_review_pure(self, term: Comprehension,
                              inner: Callable) -> Callable:
@@ -802,3 +726,148 @@ class ClosureCompiler:
                         pairs[k] = v
             yield Obj(pairs), env
         return self._memoize_review_pure(term, objc)
+
+
+def _reads(interp, heads, lits, review_ok: bool, fn_ok: Callable):
+    """The one purity visitor: None when evaluating ``heads`` and the
+    literals ``lits`` may read more than the variables they mention,
+    else the set of those variable names (wildcards left out).
+
+    Impure: ``data`` (inventory, external documents), any ``with``,
+    ``input`` except ``input.review...`` refs when ``review_ok``, a
+    reference to a rule's value (which may read the constraint), an
+    impure builtin (clock, trace, signature checks), and a call of a
+    user function unless ``fn_ok(name)``."""
+    impure = False
+    names: set[str] = set()
+
+    def visit(t):
+        nonlocal impure
+        if impure or t is None:
+            return
+        cls = t.__class__
+        if cls is Var:
+            if t.name in ("input", "data"):
+                # a BARE input or data var binds the whole document —
+                # input's .constraint included (the Ref branch below
+                # handles the safe input.review.* base inline, so this
+                # branch only sees whole-document references)
+                impure = True
+                return
+            if t.is_wildcard:
+                return
+            if t.name in interp.rules:
+                impure = True       # rule value: may read constraint
+            else:
+                names.add(t.name)
+            return
+        if cls is Ref:
+            base = t.base
+            if base.__class__ is Var:
+                if base.name == "data":
+                    impure = True   # inventory / external docs
+                    return
+                if base.name == "input":
+                    p0 = t.path[0] if t.path else None
+                    if not (review_ok and p0 is not None
+                            and p0.__class__ is Scalar
+                            and p0.value == "review"):
+                        impure = True   # input.constraint / dynamic
+                        return
+                    for p in t.path:
+                        visit(p)
+                    return
+            visit(base)
+            for p in t.path:
+                visit(p)
+            return
+        if cls is Call:
+            nm = t.name
+            if is_impure_call(nm, interp.rules) and not fn_ok(nm):
+                impure = True       # impure builtin (clock/trace/jwt
+                return              # verify) or user function — one
+                #                     gate shared with the template
+                #                     vetter (analysis/purity.py)
+            for a in t.args:
+                visit(a)
+            return
+        if cls is Scalar:
+            return
+        if cls is Comprehension:
+            for h in t.head:
+                visit(h)
+            for lit in t.body:
+                visit_lit(lit)
+            return
+        if cls in (ArrayTerm, SetTerm):
+            for x in t.items:
+                visit(x)
+            return
+        if cls is ObjectTerm:
+            for k, v in t.pairs:
+                visit(k)
+                visit(v)
+            return
+        if cls is BinOp:
+            visit(t.lhs)
+            visit(t.rhs)
+            return
+        if cls is UnaryMinus:
+            visit(t.operand)
+            return
+        impure = True               # unknown node kind: stay safe
+
+    def visit_lit(lit):
+        nonlocal impure
+        if impure:
+            return
+        if lit.withs:
+            impure = True           # document override inside
+            return
+        e = lit.expr
+        if e.__class__ in (Compare, Assign):
+            visit(e.lhs)
+            visit(e.rhs)
+        elif e.__class__ is SomeDecl:
+            names.update(e.names)
+        else:
+            visit(e)
+
+    for h in heads:
+        visit(h)
+    for lit in lits:
+        visit_lit(lit)
+    return None if impure else names
+
+
+def pure_functions(interp) -> frozenset:
+    """Names of the user functions whose value depends on their
+    arguments alone: no clause (``else`` clauses included) reads
+    ``input`` or ``data``, uses ``with``, references a rule's value or
+    calls an impure builtin, and every user function called is itself
+    pure.  A function on a call cycle is never pure.  By Rego's
+    semantics a call of such a function may be served from a memo
+    keyed by its argument values (interp._call_function)."""
+    state: dict[str, bool] = {}
+
+    def callee_ok(nm) -> bool:
+        return not is_impure_builtin(nm) and ok(nm[0])
+
+    def ok(name: str) -> bool:
+        got = state.get(name)
+        if got is not None:
+            return got
+        state[name] = False         # in progress: a cycle is impure
+        rules = interp.rules.get(name, ())
+        pure = bool(rules)
+        for rule in rules:
+            clause = rule
+            while pure and clause is not None:
+                pure = clause.kind == "function" and _reads(
+                    interp, (*clause.args, clause.value), clause.body,
+                    review_ok=False, fn_ok=callee_ok) is not None
+                clause = clause.els
+        state[name] = pure
+        return pure
+
+    return frozenset(n for n in interp.rules if ok(n))

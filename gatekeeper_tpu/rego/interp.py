@@ -67,6 +67,14 @@ class Interpreter:
         self._builtinfn: dict[int, Any] = {}  # Call -> resolved builtin
         for r in self.module.rules:
             _walk_rule(r, self._index_term)
+        # user functions whose calls the per-review shared memo may
+        # serve (rego/closures.pure_functions); the counters tally its
+        # hits and misses for the format span (plain ints: a race
+        # between threads can only lose a count)
+        from gatekeeper_tpu.rego.closures import pure_functions
+        self._pure_fns = pure_functions(self)
+        self.fn_memo_hits = 0
+        self.fn_memo_misses = 0
         # closure-compiled body tier (rego/closures.py): rule bodies run
         # as pre-compiled closure trees; the recursive path below stays
         # the oracle (GATEKEEPER_NO_CLOSURES=1 forces it, and the parity
@@ -226,6 +234,30 @@ class Interpreter:
     def _call_function(self, ctx: _Ctx, name: str, argvals: tuple) -> Any:
         if ctx.depth > _MAX_DEPTH:
             raise EvalError(f"max call depth exceeded in {name}")
+        shared = ctx.shared
+        if shared is None or ctx.step is not None \
+                or name not in self._pure_fns:
+            return self._apply_function(ctx, name, argvals)
+        # a pure function's value depends on its arguments alone: the
+        # per-review shared memo serves it across the constraint loop.
+        # Scalar arguments only, each keyed with its type (true, 1 and
+        # "1" take different clauses, yet Python holds True == 1); an
+        # exception is never stored
+        key = [self, name]
+        for a in argvals:
+            if a.__class__ not in _SCALARS:
+                return self._apply_function(ctx, name, argvals)
+            key += (a.__class__, a)
+        key = tuple(key)
+        v = shared.get(key, _MISS)
+        if v is not _MISS:
+            self.fn_memo_hits += 1
+            return v
+        self.fn_memo_misses += 1
+        v = shared[key] = self._apply_function(ctx, name, argvals)
+        return v
+
+    def _apply_function(self, ctx: _Ctx, name: str, argvals: tuple) -> Any:
         rules = self.rules.get(name, [])
         st = ctx.step
         if st is not None:
@@ -720,6 +752,7 @@ class Interpreter:
 
 _IN_PROGRESS = object()
 _MISS = object()
+_SCALARS = frozenset({str, int, float, bool, type(None)})
 
 
 def _walk_rule(rule: Rule, visit) -> None:
